@@ -1264,6 +1264,9 @@ def main(argv: Optional[List[str]] = None) -> int:
     )
     ap.add_argument("--compare-tolerance", type=float, default=0.35)
     args = ap.parse_args(argv)
+    from repro.kernels.dispatch import enable_compile_cache
+
+    enable_compile_cache()
     # Benchmarks default to the on-disk world cache so repeated invocations
     # skip the one-off builds; opt out with REPRO_WORLD_CACHE=0.
     os.environ.setdefault("REPRO_WORLD_CACHE", "1")

@@ -6,16 +6,16 @@
 Demonstrates the programming model (paper §2.3): each app is a handful of
 lines — only the module logics change, the dataflow is fixed — and a
 composed :class:`TrackingApp` is the platform's executable unit.  The main
-program runs all four apps through ``SweepRunner`` (fork pool where
-available): each grid case pairs an app *factory* with a workload, the
-worker builds the app against the shared world and
-``repro.core.compile.compile_app`` lowers it onto the discrete-event
-pipeline (App 2 exercising the QF query-fusion feedback edge, App 4 the
-real JAX re-id towers through the bucket-batched kernel dispatch plane).
+program runs all four apps through ``SweepRunner``: each grid case pairs an
+app *factory* with a workload, the runner builds the app against the
+shared world and ``repro.core.compile.compile_app`` lowers it onto the
+discrete-event pipeline (App 2 exercising the QF query-fusion feedback
+edge, App 4 the real JAX re-id towers through the bucket-batched kernel
+dispatch plane).
 
-App factories (not instances) go into the grid so JAX-touching apps
-construct *inside* the fork workers — the parent never initializes a JAX
-backend before forking.
+Apps 1-3 are pure discrete-event simulation and run in a fork pool where
+available.  App 4 dispatches to the JAX device, which takes one process, so
+it runs afterwards in this process.
 """
 
 import sys
@@ -246,21 +246,28 @@ def build_apps(road=None, cameras=None):
 
 
 def main() -> None:
+    from repro.kernels.dispatch import enable_compile_cache
+
+    enable_compile_cache()
     # ---- execute: the composed apps ARE the runnable artifact ---------- #
-    # (Run first: app factories construct JAX-touching apps inside the
-    # fork workers, so the parent forks before any JAX backend exists.)
+    # The pure-DES apps fork first, while no JAX backend exists in this
+    # process; App 4 then brings JAX up here.
+    grid = table1_grid("dynamic")
+    des_grid = [(name, case) for name, case in grid if not case.needs_jax]
+    jax_grid = [(name, case) for name, case in grid if case.needs_jax]
     mode = "fork" if SweepRunner.fork_available() else "serial"
     print(f"Running the four Table-1 apps end-to-end (SweepRunner, {mode})...\n")
-    res = SweepRunner(mode=mode).run(table1_grid("dynamic"))
-    for rec in res.records:
-        s = rec.summary
-        print(
-            f"  {rec.name}: events={s['source_events']} on_time={s['on_time']} "
-            f"delayed={s['delayed']} peak_active={s['peak_active']} "
-            f"positives={s['positives_completed']}/{s['positives_generated']} "
-            f"({rec.run_s:.2f}s run)"
-        )
-    print(f"\nSweep: mode={res.mode} workers={res.workers} wall={res.wall_s:.2f}s")
+    for res in (SweepRunner(mode=mode).run(des_grid),
+                SweepRunner(mode="serial").run(jax_grid)):
+        for rec in res.records:
+            s = rec.summary
+            print(
+                f"  {rec.name}: events={s['source_events']} on_time={s['on_time']} "
+                f"delayed={s['delayed']} peak_active={s['peak_active']} "
+                f"positives={s['positives_completed']}/{s['positives_generated']} "
+                f"({rec.run_s:.2f}s run)"
+            )
+        print(f"\nSweep: mode={res.mode} workers={res.workers} wall={res.wall_s:.2f}s")
 
     # ---- compose: the DSL-conciseness exhibit -------------------------- #
     apps = build_apps()

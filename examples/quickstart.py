@@ -33,6 +33,9 @@ def openreid_matcher(crops, query):
 
 
 def main() -> None:
+    from repro.kernels.dispatch import enable_compile_cache
+
+    enable_compile_cache()
     # --- the workload: 1000 cameras, 300 s, the paper's entity walk ------ #
     cfg = ScenarioConfig(num_cameras=1000, duration_s=300.0)
     world = get_world(WorldKey.from_config(cfg))

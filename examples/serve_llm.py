@@ -27,6 +27,9 @@ from repro.core.events import Event, EventHeader, new_event_id
 
 
 def main() -> None:
+    from repro.kernels.dispatch import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-1.5b")
     ap.add_argument("--requests", type=int, default=24)

@@ -29,6 +29,9 @@ from repro.sim import ScenarioConfig, TrackingScenario
 
 
 def main() -> None:
+    from repro.kernels.dispatch import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--cameras", type=int, default=500)
     ap.add_argument("--duration", type=float, default=240.0)

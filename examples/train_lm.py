@@ -36,6 +36,9 @@ def hundred_m_config(arch: str):
 
 
 def main() -> None:
+    from repro.kernels.dispatch import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
     ap.add_argument("--steps", type=int, default=300)

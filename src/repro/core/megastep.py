@@ -568,10 +568,11 @@ def try_run_megastep(scn):
         scn.engine_fallback_reason = ""
         return None
     seed = _seed_applied(live, plan.num_cameras)
+    fallback = ""
     if backend == "device":
-        out = _run_device(scn, plan, seed)
+        out, fallback = _run_device(scn, plan, seed)
         if out is None:
-            backend = "host"  # jax missing or shape divergence: host mirror
+            backend = "host"
     if backend == "host":
         if scn._spotlight_mode == "kernel" or any(
             type(st.tl) not in (TLBase, TLBFS, TLWBFS) for st in live
@@ -585,7 +586,9 @@ def try_run_megastep(scn):
         scn.engine_used = "megastep-host"
     else:
         scn.engine_used = "megastep-device"
-    scn.engine_fallback_reason = ""
+    # A device run handed to the host mirror says why (x64-emulated,
+    # device-capacity, device-error: ...); a run classified host has none.
+    scn.engine_fallback_reason = fallback
     if out.final_req is not None:
         # Leave the registry's requested sets at the last TL tick's targets
         # (the object-TL callback already does; the table/device paths
@@ -614,8 +617,8 @@ def _sync_control_mirrors(scn, live) -> None:
 
 
 def _run_device(scn, plan: MegastepPlan, seed_applied: np.ndarray):
-    """Device scan backend; returns a ChainOutput or None (unavailable /
-    diverged beyond the largest bucket).
+    """Device scan backend; returns ``(ChainOutput, "")``, or ``(None,
+    reason)`` when the run must go to the host mirror.
 
     With a mesh handle (``MultiQueryScenario(..., mesh=...)`` /
     ``distributed.camera_mesh()``) the scan runs camera-sharded via
@@ -624,12 +627,8 @@ def _run_device(scn, plan: MegastepPlan, seed_applied: np.ndarray):
     ``scn.shard_fallback_reason`` — the GRF005 totality contract extended
     to sharding — and the run continues bit-identically on the unsharded
     single-shard path."""
-    try:
-        from ..kernels.megastep import ops as _ops
-    except ImportError:  # jax unavailable: host reference takes over
-        return None
-    if plan.modes is None:
-        return None
+    from ..kernels.megastep import ops as _ops
+
     rules = getattr(scn, "mesh_rules", None)
     if rules is not None:
         from ..kernels.megastep import sharded as _sharded
@@ -645,12 +644,13 @@ def _run_device(scn, plan: MegastepPlan, seed_applied: np.ndarray):
             chunk_walls = _sharded.last_chunk_seconds()
             scn.megastep_chunk_s = sum(chunk_walls)
             scn.megastep_chunks = len(chunk_walls)
-            return out
+            return out, ""
         scn.shard_fallback_reason = _sharded.last_error() or "unclassified"
     out = _ops.run_chain_device(plan, seed_applied)
-    if out is not None:
-        scn.engine_xfer_s = _ops.last_xfer_seconds()
-        chunk_walls = _ops.last_chunk_seconds()
-        scn.megastep_chunk_s = sum(chunk_walls)
-        scn.megastep_chunks = len(chunk_walls)
-    return out
+    if out is None:
+        return None, _ops.last_fallback_reason() or "unclassified"
+    scn.engine_xfer_s = _ops.last_xfer_seconds()
+    chunk_walls = _ops.last_chunk_seconds()
+    scn.megastep_chunk_s = sum(chunk_walls)
+    scn.megastep_chunks = len(chunk_walls)
+    return out, ""

@@ -51,6 +51,8 @@ __all__ = [
     "profile",
     "jit_cache_sizes",
     "bound_jit_cache",
+    "pallas_interpret",
+    "enable_compile_cache",
 ]
 
 BUCKET_MIN = 8
@@ -143,10 +145,7 @@ def bound_jit_cache(name: str, fn, key: Tuple, cap: Optional[int] = None) -> Non
         return
     lru[key] = None
     if len(lru) > cap:
-        try:
-            fn.clear_cache()
-        except AttributeError:
-            pass
+        fn.clear_cache()
         lru.clear()
         lru[key] = None
 
@@ -160,6 +159,36 @@ def _use_pallas() -> bool:
     if force == "0":
         return False
     return jax.default_backend() == "tpu"
+
+
+# The checkout root (``src/repro/kernels/dispatch.py`` -> three up).
+_CHECKOUT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+)
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, where set, is JAX's own setting and is
+    left as it is.  Otherwise the cache goes to ``<checkout>/.jax_cache``,
+    one fixed path, so every process run from this checkout shares it.
+    Entry points call this before their first compile."""
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
+    if not path:
+        path = os.path.join(_CHECKOUT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def pallas_interpret() -> bool:
+    """Pallas kernels run interpreted everywhere but on a TPU, where they
+    always compile (Mosaic)."""
+    import jax
+
+    return jax.default_backend() != "tpu"
 
 
 # --------------------------------------------------------------------- #
@@ -294,7 +323,7 @@ def spotlight_ball(indptr, indices, weights, sources, radii, *, dtype=np.float32
 
     W = _dense_w(indptr, indices, weights, dtype)
     use_pallas = _use_pallas()
-    interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret()
     if _BALL_PADDED is None:
         _BALL_PADDED = _make_ball_padded()
     key = ("ball", int(W.shape[0]), qb, np.dtype(dtype).str, use_pallas)
@@ -332,7 +361,8 @@ def _make_reid_padded():
         q = queries.astype(jnp.float32)
         g = g / jnp.maximum(jnp.linalg.norm(g, axis=-1, keepdims=True), 1e-6)
         q = q / jnp.maximum(jnp.linalg.norm(q, axis=-1, keepdims=True), 1e-6)
-        sim = g @ q.T  # (N, Qb)
+        # HIGHEST: a TPU runs a default-precision f32 matmul as bf16 passes.
+        sim = jnp.matmul(g, q.T, precision=jax.lax.Precision.HIGHEST)  # (N, Qb)
         valid = jnp.arange(q.shape[0])[None, :] < nq
         sim = jnp.where(valid, sim, -jnp.inf)
         scores = jnp.max(sim, axis=-1)
@@ -501,24 +531,15 @@ def reid_match_multi(gallery, queries, *, mask=None, threshold: float = 0.5):
 def jit_cache_sizes() -> Dict[str, int]:
     """Number of distinct compilations held by each padded kernel (0 when
     the kernel has not been dispatched yet)."""
-    try:  # the mega-step scan shares the bounded-jit-cache contract
-        from .megastep import ops as _mega_ops
+    # the mega-step scan shares the bounded-jit-cache contract
+    from .megastep import ops as _mega_ops
 
-        mega_fn = _mega_ops._CHUNK_FN
-    except ImportError:  # jax/megastep stack absent: report cache size 0
-        mega_fn = None
-    sizes = {}
-    for name, fn in (
-        ("ball", _BALL_PADDED),
-        ("reid", _REID_PADDED),
-        ("reid_multi", _REID_MULTI_PADDED),
-        ("megastep", mega_fn),
-    ):
-        if fn is None:
-            sizes[name] = 0
-            continue
-        try:
-            sizes[name] = fn._cache_size()
-        except AttributeError:  # older jax: fall back to tracked shapes
-            sizes[name] = sum(1 for s in _SHAPES if s[0] == name)
-    return sizes
+    return {
+        name: 0 if fn is None else fn._cache_size()
+        for name, fn in (
+            ("ball", _BALL_PADDED),
+            ("reid", _REID_PADDED),
+            ("reid_multi", _REID_MULTI_PADDED),
+            ("megastep", _mega_ops._CHUNK_FN),
+        )
+    }

@@ -10,8 +10,14 @@ which is what makes the kernel bit-identical to the numpy chain.
 The sweep is inherently sequential per lane (slot ``s+1``'s start depends
 on slot ``s``'s end), so the kernel is a ``fori_loop`` over slots with the
 chain state in scalars; lanes are the grid.  Validated in interpret mode
-against the jnp inner-scan in ``ops`` (see ``tests/test_megastep_props``);
-on hardware without native f64 the engine keeps the jnp path.
+against the jnp inner-scan in ``ops`` (see ``tests/test_megastep_props``).
+
+The engine chooses this kernel from the chain state's dtype, not by trying
+to compile it (``ops.lane_chain_uses_pallas``).  The TPU compiler refuses
+64-bit operands in a Pallas call, and the chain state is f64, so the jnp
+slot scan is the lane chain on every platform.  Its ``(1, S)`` slot blocks
+do not fit the TPU's (8, 128) tiling either: the kernel runs interpreted
+only.
 """
 
 from __future__ import annotations

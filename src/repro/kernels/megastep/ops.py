@@ -12,7 +12,10 @@ per-(tick, lane, slot) summary rows come back to the host, which rebuilds
 Bit-exactness: every float op is an f64 add/sub/compare in the exact order
 of the numpy reference (no multiplies anywhere on the device path, so no
 FMA contraction; tables carrying the radius arithmetic are host-built), so
-rows are bit-identical to ``ref.run_chain`` + ``ref.make_table_tl``.
+rows are bit-identical to ``ref.run_chain`` + ``ref.make_table_tl`` —
+where the backend's f64 is IEEE binary64 (:func:`x64_exact`).  A TPU
+lowers f64 to f32 pairs; there the scan does not run and the caller takes
+the host reference, reason ``x64-emulated``.
 
 Shapes are bucket-padded (cameras, queries, lane slots, detection ring,
 ticks-per-chunk, table dims) so a sweep compiles the scan at most once per
@@ -27,7 +30,7 @@ from __future__ import annotations
 
 import math
 import time
-from typing import List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -35,6 +38,7 @@ from .. import dispatch
 from . import ref as _ref
 
 __all__ = ["run_chain_device", "last_xfer_seconds", "last_chunk_seconds",
+           "last_fallback_reason", "x64_exact", "lane_chain_uses_pallas",
            "KMAX", "RING_CAP"]
 
 KMAX = 256        # ticks per dispatch (chunk) cap
@@ -46,7 +50,10 @@ _CHUNK_FN = None
 # per-chunk summary pulls + the final carry).  Benchmarks report this as
 # the separate ``xfer_s`` column so compute and transfer don't blur.
 _LAST_XFER_S = 0.0
-_LAST_DEVICE_ERROR = ""
+_LAST_FALLBACK = ""
+# Per backend: does f64 add/sub/compare there round like IEEE binary64?
+# Bit-identity with the host reference rests on it (module docstring).
+_X64_EXACT: Dict[str, bool] = {}
 # Per-chunk host wall (dispatch + device compute + summary pull) of the most
 # recent run_chain_device call — the observability plane's mega-step profile
 # (repro.obs.collect_engine).  Attribution only, never a decision input.
@@ -64,13 +71,48 @@ def last_chunk_seconds() -> list:
     return list(_CHUNK_WALL_S)
 
 
-def last_device_error() -> str:
-    """repr() of the exception that made the most recent
-    :func:`run_chain_device` call hand the run to the host reference
-    ("" when the device path succeeded or was never tried).  The broad
-    catch is intentional — *any* backend failure must fall back, exactness
-    preserved — but it must stay observable, not silent."""
-    return _LAST_DEVICE_ERROR
+def last_fallback_reason() -> str:
+    """Why the most recent :func:`run_chain_device` call returned None:
+    ``"x64-emulated"``, ``"queries>64"``, ``"device-capacity"`` or
+    ``"device-error: <repr>"`` ("" when the scan ran or was never tried).
+    The catch behind the last one is broad on purpose — *any* backend
+    failure must fall back, exactness preserved — but never silently."""
+    return _LAST_FALLBACK
+
+
+def x64_exact() -> bool:
+    """Whether the default backend adds, subtracts and compares f64 exactly
+    as IEEE binary64 does, round trip to the host included.  A TPU lowers
+    f64 to pairs of f32, which keeps about 48 mantissa bits and f32's
+    exponent range, so its sums differ from the host reference's in the
+    last bits.  Probed once per backend on seeded operands that use the
+    full 53-bit mantissa."""
+    import jax
+
+    backend = jax.default_backend()
+    ok = _X64_EXACT.get(backend)
+    if ok is None:
+        rng = np.random.default_rng(0)
+        a = rng.uniform(-1e3, 1e3, 1024)
+        b = rng.uniform(-1e3, 1e3, 1024)
+        with jax.enable_x64(True):
+            s, d, lt = jax.device_get(
+                jax.jit(lambda x, y: (x + y, x - y, x + y < y))(a, b)
+            )
+        ok = bool(
+            np.array_equal(s, a + b)
+            and np.array_equal(d, a - b)
+            and np.array_equal(lt, a + b < b)
+        )
+        _X64_EXACT[backend] = ok
+    return ok
+
+
+def lane_chain_uses_pallas(dtype) -> bool:
+    """Whether the chain sweep runs as the Pallas lane-chain kernel for
+    chain state of ``dtype``.  Mosaic refuses 64-bit operands in a Pallas
+    call, so f64 state keeps the jnp slot scan on every platform."""
+    return dispatch._use_pallas() and np.dtype(dtype).itemsize <= 4
 
 
 def _build_chunk_fn():
@@ -354,19 +396,27 @@ def _assemble(plan, seed_applied, ys, final_applied, d_vc, d_cu,
     auv_e = a_uv[ts, ls_, ss]
     pos_e = pos[ts, ls_, ss]
 
+    # Rows come in (tick, lane, slot) order: each lane's chain order.
+    va_line = [_ref.QueueLineage() for _ in range(plan.num_lanes)]
+    cr_line = [_ref.QueueLineage() for _ in range(plan.num_lanes)]
     rows: List[_ref.SinkRow] = []
     for e in range(len(ts)):
         t = int(ts[e])
+        lane = int(ls_[e])
+        grank, slot = int(gr_e[e]), int(ss[e])
         now = float(ftimes[t])
         a = float(auv_e[e])
         vend = float(vend_e[e])
+        va_fused, cr_fused = bool(vafu_e[e]), bool(crfu_e[e])
         rows.append(_ref.SinkRow(
-            a_uv=a, tick=t, grank=int(gr_e[e]), slot=int(ss[e]),
-            lane=int(ls_[e]), cam=int(cam_e[e]), positive=bool(pos_e[e]),
+            a_uv=a, tick=t, grank=grank, slot=slot,
+            lane=lane, cam=int(cam_e[e]), positive=bool(pos_e[e]),
             u=a - now, q_bar=(0.0 + float(qva_e[e])) + float(qcr_e[e]),
-            va_fused=bool(vafu_e[e]), va_end=vend, cr_arr=vend + d_vc,
-            cr_fused=bool(crfu_e[e]), cr_end=float(cend_e[e]),
+            va_fused=va_fused, va_end=vend, cr_arr=vend + d_vc,
+            cr_fused=cr_fused, cr_end=float(cend_e[e]),
             mask=masks[e],
+            order=_ref.sink_order(va_line[lane], cr_line[lane], t, grank,
+                                  slot, va_fused, cr_fused, vend + d_vc),
         ))
     rows.sort(key=_ref.sink_sort_key)
 
@@ -411,42 +461,41 @@ def _assemble(plan, seed_applied, ys, final_applied, d_vc, d_cu,
 
 
 def run_chain_device(plan, seed_applied) -> Optional[_ref.ChainOutput]:
-    """Run the fused scan on device; None means "use the host reference"
-    (jax unavailable, capacities exceeded, or any backend failure)."""
-    global _CHUNK_FN, _LAST_XFER_S, _LAST_DEVICE_ERROR
-    if plan.modes is None:
-        return None
-    try:
-        import jax
-        import jax.numpy as jnp
-        from jax.experimental import enable_x64
-    except ImportError:  # no jax: the caller falls back to the host ref
-        return None
+    """Run the fused scan on device; None means "use the host reference",
+    with the reason in :func:`last_fallback_reason`."""
+    global _CHUNK_FN, _LAST_XFER_S, _LAST_FALLBACK
+    import jax
+    import jax.numpy as jnp
+
     _LAST_XFER_S = 0.0
-    _LAST_DEVICE_ERROR = ""
+    _LAST_FALLBACK = ""
     del _CHUNK_WALL_S[:]
+    N = seed_applied.shape[0]
+    Nb = min(dispatch.bucket(N), 64)
+    if N > Nb:
+        _LAST_FALLBACK = "queries>64"
+        return None
 
     try:
-        with enable_x64():
+        if not x64_exact():
+            _LAST_FALLBACK = "x64-emulated"
+            return None
+        with jax.enable_x64(True):
             if _CHUNK_FN is None:
                 _CHUNK_FN = _build_chunk_fn()
             fn = _CHUNK_FN
 
             C = plan.num_cameras
-            N = seed_applied.shape[0]
             L = plan.num_lanes
             T = len(plan.ftimes)
             Cb = dispatch.bucket(C)
-            Nb = min(dispatch.bucket(N), 64)
-            if N > Nb:
-                return None
             Tb = dispatch.bucket(T)
             K = min(dispatch.bucket(T), KMAX)
             nchunk = (T + K - 1) // K
 
             tables_np, (Gb, NCb, U) = _plan_device_tables(plan, jnp, Nb, Cb, Tb)
-            use_pallas = dispatch._use_pallas()
-            interpret = jax.default_backend() != "tpu"
+            use_pallas = lane_chain_uses_pallas(np.float64)
+            interpret = dispatch.pallas_interpret()
             scalars = tuple(
                 jnp.asarray(v, jnp.float64)
                 for v in (plan.xi_fc, plan.xi_va, plan.xi_cr,
@@ -537,10 +586,11 @@ def run_chain_device(plan, seed_applied) -> Optional[_ref.ChainOutput]:
                     R = min(R * 2, RING_CAP)
                     grew = True
                 if not grew:
+                    _LAST_FALLBACK = "device-capacity"
                     return None
     except Exception as e:
         # Intentionally broad: whatever kills the device backend (XLA,
         # driver, shape divergence), the host reference takes over and the
         # result stays bit-exact — but the reason is recorded, not dropped.
-        _LAST_DEVICE_ERROR = repr(e)
+        _LAST_FALLBACK = f"device-error: {e!r}"
         return None

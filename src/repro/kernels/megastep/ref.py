@@ -30,7 +30,8 @@ from typing import Callable, List, Optional, Tuple
 
 import numpy as np
 
-__all__ = ["SinkRow", "ChainOutput", "run_chain", "make_table_tl", "sink_sort_key"]
+__all__ = ["SinkRow", "ChainOutput", "QueueLineage", "run_chain", "make_table_tl",
+           "sink_sort_key"]
 
 
 @dataclass
@@ -41,7 +42,7 @@ class SinkRow:
     __slots__ = (
         "a_uv", "tick", "grank", "slot", "lane", "cam", "positive",
         "u", "q_bar", "va_fused", "va_end", "cr_arr", "cr_fused", "cr_end",
-        "mask",
+        "mask", "order",
     )
     a_uv: float      # sink arrival time
     tick: int        # source frame tick index
@@ -58,15 +59,57 @@ class SinkRow:
     cr_fused: bool
     cr_end: float
     mask: np.ndarray  # (N,) bool: per-query tag bits at source time
+    order: tuple      # heap order among equal a_uv (see sink_sort_key)
 
 
-def sink_sort_key(r: SinkRow) -> Tuple[float, int, int, int]:
-    """Sink processing order.  Heap order is (time, seq); for equal arrival
-    times the scheduling cascade preserves, per source tick, the VA
-    delivery-group creation order (rank of each lane's first active camera)
-    and the slot order within a lane; across ticks the earlier tick's
-    events were scheduled earlier and thus carry smaller seqs."""
-    return (r.a_uv, r.tick, r.grank, r.slot)
+def sink_sort_key(r: SinkRow) -> Tuple[float, tuple]:
+    """Sink processing order: the scheduler's heap order (time, seq).
+
+    Among equal arrival times seq follows the order in which the arrivals
+    were scheduled, which ``SinkRow.order`` replays (:class:`QueueLineage`
+    builds it).  A fused exec schedules its downstream arrival while its
+    own arrival is processed; a queued one at its exec end, later, so
+    fused rows go first.  A queued exec's finish callback was scheduled by
+    its predecessor's, back to the drain armed by the first queued arrival
+    of the busy period: between two queued rows ending together, the
+    deeper chain reaches back past the other's drain and goes first, and
+    at equal depth the earlier drain does.  Each arrival's own order is
+    the same comparison one stage up (CR arrivals carry their VA
+    scheduling), ending at the fused FC delivery: source tick, VA
+    delivery group (rank of each lane's first active camera), slot."""
+    return (r.a_uv, r.order)
+
+
+class QueueLineage:
+    """Heap-order lineage of one task instance's busy chain: the same
+    fused / first-queued / queued states as :class:`_LaneChain`, tracking
+    how many queued execs deep the current one is and which arrival armed
+    the drain that started the queue."""
+
+    __slots__ = ("depth", "root")
+
+    def __init__(self) -> None:
+        self.depth = -1   # -1: no queue armed since the last fused exec
+        self.root: tuple = ()
+
+    def key(self, fused: bool, arrival_order: tuple) -> tuple:
+        """Order of the downstream arrival this exec schedules, given the
+        order of its own arrival."""
+        if fused:
+            self.depth = -1
+            return (0, arrival_order)
+        if self.depth < 0:
+            self.root = arrival_order
+        self.depth += 1
+        return (1, -self.depth, self.root)
+
+
+def sink_order(va: QueueLineage, cr: QueueLineage, tick: int, grank: int,
+               slot: int, va_fused: bool, cr_fused: bool, cr_arr: float) -> tuple:
+    """``SinkRow.order`` of the next exec of one lane (rows of a lane must
+    be fed in chain order: tick, then slot)."""
+    cr_arrival = (cr_arr, va.key(va_fused, (tick, grank, slot)))
+    return cr.key(cr_fused, cr_arrival)
 
 
 @dataclass
@@ -142,6 +185,8 @@ def run_chain(
 
     va = [_LaneChain() for _ in range(L)]
     cr = [_LaneChain() for _ in range(L)]
+    va_line = [QueueLineage() for _ in range(L)]
+    cr_line = [QueueLineage() for _ in range(L)]
     draws = [0] * L
     applied = np.ascontiguousarray(seed_applied, dtype=bool)
     N = applied.shape[0]
@@ -216,6 +261,8 @@ def run_chain(
                     va_fused=va_fused, va_end=va_end, cr_arr=cr_arr,
                     cr_fused=cr_fused, cr_end=cr_end,
                     mask=applied[:, c].copy(),
+                    order=sink_order(va_line[l], cr_line[l], k, grank, slot,
+                                     va_fused, cr_fused, cr_arr),
                 )
                 rows.append(row)
                 pending.append(row)

@@ -108,6 +108,7 @@ def _build_sharded_chunk_fn(mesh, axis: str):
         Tb = r_tab.shape[-1]
         U = uniforms.shape[0]
         INT_BIG = jnp.iinfo(jnp.int64).max
+        I32_BIG = jnp.iinfo(jnp.int32).max
 
         lane_ids = jnp.arange(L, dtype=jnp.int64)
         cam0 = lax.axis_index(axis).astype(jnp.int64) * Cl
@@ -189,8 +190,10 @@ def _build_sharded_chunk_fn(mesh, axis: str):
             slot = slot_l + before[lane_of]                       # global slot
             n_l = counts_all.sum(axis=0)                          # (L,)
             of_slots = of_slots | (n_l.max() > S)
-            camv = jnp.where(act_lane, cam_ids[:, None], INT_BIG)
-            min_cam = lax.pmin(camv.min(axis=0), axis)            # (L,)
+            # The TPU lowers no 64-bit all-reduce but a sum: camera ids and
+            # their sentinels fit int32, so min/max reduce in int32.
+            camv = jnp.where(act_lane, cam_ids[:, None], I32_BIG)
+            min_cam = lax.pmin(camv.min(axis=0).astype(jnp.int32), axis)
             grank = jnp.sum(
                 min_cam[None, :] < min_cam[:, None], axis=1, dtype=jnp.int64
             )
@@ -200,11 +203,11 @@ def _build_sharded_chunk_fn(mesh, axis: str):
             ok = active & (slot < S)
             scat = jnp.where(ok, lane_of * S + slot, L * S)
             cam_at = lax.pmax(
-                jnp.full(L * S, -1, dtype=jnp.int64).at[scat].set(
-                    cam_ids, mode="drop"
+                jnp.full(L * S, -1, dtype=jnp.int32).at[scat].set(
+                    cam_ids.astype(jnp.int32), mode="drop"
                 ),
                 axis,
-            ).reshape(L, S)
+            ).reshape(L, S).astype(jnp.int64)
             real_ls = cam_at >= 0
             cam_c = jnp.maximum(cam_at, 0)
             has_ls = lax.psum(
@@ -350,8 +353,8 @@ def _collective_bytes_per_tick(D: int, L: int, S: int, Nb: int) -> float:
     """Per-device bytes moved by the frontier collectives each tick."""
     return float(
         D * L * 8        # all_gather of per-lane active counts
-        + L * 8          # pmin of lane min-camera
-        + L * S * 8      # pmax of slot occupancy (cam_at)
+        + L * 4          # pmin of lane min-camera
+        + L * S * 4      # pmax of slot occupancy (cam_at)
         + L * S * 4      # psum of slot visibility
         + L * S * Nb * 4  # psum of slot tag masks
         + Nb * 8 + 8     # psum of TL counts + union size
@@ -368,13 +371,8 @@ def run_chain_sharded(plan, seed_applied, rules) -> Optional[_ref.ChainOutput]:
     if plan.modes is None:
         _LAST_ERROR = "no-table-planes"
         return None
-    try:
-        import jax
-        import jax.numpy as jnp
-        from jax.experimental import enable_x64
-    except ImportError:
-        _LAST_ERROR = "no-jax"
-        return None
+    import jax
+    import jax.numpy as jnp
 
     mesh = rules.mesh
     axis = "cameras" if "cameras" in mesh.axis_names else None
@@ -407,7 +405,10 @@ def run_chain_sharded(plan, seed_applied, rules) -> Optional[_ref.ChainOutput]:
     del _CHUNK_WALL_S[:]
 
     try:
-        with enable_x64():
+        if not _ops.x64_exact():
+            _LAST_ERROR = "x64-emulated"
+            return None
+        with jax.enable_x64(True):
             fkey = (tuple(d.id for d in mesh.devices.flat), axis)
             fn = _SHARDED_FNS.get(fkey)
             if fn is None:

@@ -42,7 +42,9 @@ def _kernel(
         jnp.sqrt(jnp.sum(g * g, axis=1, keepdims=True)), 1e-6
     )
     sim = jax.lax.dot_general(
-        g, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        g, q, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )  # (block_n, Q)
     scores = jnp.max(sim, axis=1)
     best = jnp.argmax(sim, axis=1).astype(jnp.int32)

@@ -8,6 +8,7 @@ from typing import Tuple
 
 import jax
 
+from ..dispatch import pallas_interpret
 from .ref import reid_match_ref
 
 __all__ = ["reid_match"]
@@ -31,6 +32,6 @@ def reid_match(
 
         return reid_match_pallas(
             gallery, queries, threshold=threshold,
-            interpret=jax.default_backend() != "tpu",
+            interpret=pallas_interpret(),
         )
     return reid_match_ref(gallery, queries, threshold=threshold)

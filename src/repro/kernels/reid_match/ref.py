@@ -29,7 +29,8 @@ def reid_match_ref(
     q = queries.astype(jnp.float32)
     g = g / jnp.maximum(jnp.linalg.norm(g, axis=-1, keepdims=True), 1e-6)
     q = q / jnp.maximum(jnp.linalg.norm(q, axis=-1, keepdims=True), 1e-6)
-    sim = g @ q.T  # (N, Q)
+    # HIGHEST: a TPU runs a default-precision f32 matmul as bf16 passes.
+    sim = jnp.matmul(g, q.T, precision=jax.lax.Precision.HIGHEST)  # (N, Q)
     scores = jnp.max(sim, axis=-1)
     best = jnp.argmax(sim, axis=-1).astype(jnp.int32)
     return scores, best, scores >= threshold

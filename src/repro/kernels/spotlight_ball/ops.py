@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..dispatch import pallas_interpret
 from .ref import dense_adjacency, relax_step_ref, spotlight_ball_ref
 
 __all__ = ["spotlight_ball"]
@@ -78,6 +79,6 @@ def spotlight_ball(
         D0 = jnp.full((Q, V), inf, dtype=W.dtype)
         D0 = D0.at[jnp.arange(Q), sources].set(jnp.zeros((), dtype=W.dtype))
         return _iterate_pallas(
-            W, D0, radii, interpret=jax.default_backend() != "tpu"
+            W, D0, radii, interpret=pallas_interpret()
         )
     return spotlight_ball_ref(W, sources, radii)

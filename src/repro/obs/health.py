@@ -59,15 +59,14 @@ def probe_journal(journal, t_now: Optional[float] = None,
 
 def probe_backend() -> Probe:
     """The kernel plane is unhealthy once a device call has failed and
-    forced the host-reference fallback (``dispatch.last_device_error``)."""
-    try:
-        from repro.kernels.megastep import ops
-    except Exception as e:  # pragma: no cover - import cycle guard
-        return ("backend", False, f"kernel plane unavailable: {e!r}")
-    err = ops.last_device_error()
-    if not err:
+    forced the host-reference fallback (``ops.last_fallback_reason``); a
+    refusal by design (``x64-emulated``, capacity) is not a failure."""
+    from repro.kernels.megastep import ops
+
+    reason = ops.last_fallback_reason()
+    if not reason.startswith("device-error"):
         return ("backend", True, "device path clean")
-    return ("backend", False, f"device fallback active: {err}")
+    return ("backend", False, f"device fallback active: {reason}")
 
 
 def _aggregate(probes: List[Probe]) -> Dict[str, object]:

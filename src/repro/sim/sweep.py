@@ -33,6 +33,7 @@ from __future__ import annotations
 import gc
 import multiprocessing
 import os
+import sys
 import time
 from dataclasses import dataclass, replace
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -113,6 +114,29 @@ def _workload(case) -> ScenarioConfig:
     """The ScenarioConfig a grid entry runs over (identity for plain
     configs, the embedded workload for app/query cases)."""
     return case.workload if isinstance(case, (AppCase, QueryCase)) else case
+
+
+def _touches_jax(case) -> bool:
+    """Whether a grid case dispatches work to a JAX device: re-ID
+    embeddings, the fused mega-step engine, kernel spotlights, or an app
+    declared ``needs_jax``."""
+    cfg = _workload(case)
+    return (
+        cfg.embed_dim > 0
+        or cfg.engine == "megastep"
+        or (isinstance(case, AppCase) and case.needs_jax)
+        or (isinstance(case, QueryCase) and case.spotlight_mode == "kernel")
+    )
+
+
+def _jax_initialized() -> bool:
+    """Whether this process has brought up a JAX backend (and with it the
+    XLA threads and, on a TPU host, the chip)."""
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized()
 
 
 def _run_case(name: str, case) -> CaseRecord:
@@ -235,13 +259,22 @@ class SweepRunner:
             # a fork pool — results must be identical either way).
             if not self.fork_available():
                 raise RuntimeError("fork start method unavailable on this platform")
+            if needs_jax and _jax_initialized():
+                # A forked child inherits the parent's XLA threads and, on a
+                # TPU host, its claim on the chip: the child hangs or fails.
+                raise RuntimeError(
+                    "this grid dispatches to a JAX device and JAX is already "
+                    "initialised in this process: forking it would share "
+                    "the device; run it with mode='serial'"
+                )
             return "fork", workers
         if self.mode == "serial" or workers == 1 or not self.fork_available():
             return "serial", 1
         if needs_jax:
             # JAX (multithreaded XLA) in a forked child of a JAX-initialized
-            # parent can deadlock; grids whose scenarios dispatch kernels
-            # (embed_dim re-id) run serially unless fork is forced.
+            # parent can deadlock, and a chip takes one process: grids whose
+            # scenarios dispatch to a device run serially unless fork is
+            # forced.
             return "serial", 1
         return "fork", workers
 
@@ -278,11 +311,7 @@ class SweepRunner:
         # True builds only: LRU/disk hits during the prebuild don't count.
         worlds_built = world_cache_stats()["builds"] - builds_before
         world_build_total = world_build_s
-        needs_jax = any(
-            _workload(case).embed_dim > 0
-            or (isinstance(case, AppCase) and case.needs_jax)
-            for _, case in grid
-        )
+        needs_jax = any(_touches_jax(case) for _, case in grid)
         if not self.share_worlds:
             # The cold baseline is by definition sequential (per-case cache
             # clearing cannot be meaningful across concurrent workers).

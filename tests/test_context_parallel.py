@@ -73,7 +73,7 @@ SHARD_MAP_SCRIPT = textwrap.dedent(
     from repro.distributed.context_parallel import context_parallel_decode_attention
     from repro.kernels.decode_attention.ref import decode_attention_ref
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
     key = jax.random.PRNGKey(0)
     B, Hq, Hkv, T, D = 2, 4, 2, 128, 32
     q = jax.random.normal(key, (B, Hq, D))

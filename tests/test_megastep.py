@@ -76,11 +76,11 @@ def _run(cfg, specs, engine, **mq_kw):
     return _deep(res), scn.engine_used, scn.engine_fallback_reason
 
 
-def check_bit_identical(cfg, specs, expect_engine, **mq_kw):
+def check_bit_identical(cfg, specs, expect_engine, expect_reason="", **mq_kw):
     ref, ref_engine, _ = _run(cfg, specs, "interpreted", **mq_kw)
     assert ref_engine == "interpreted"
     got, engine, reason = _run(cfg, specs, "megastep", **mq_kw)
-    assert engine == expect_engine, (engine, reason)
+    assert (engine, reason) == (expect_engine, expect_reason)
     assert got == ref
     return got
 
@@ -111,6 +111,26 @@ def test_device_multi_lane():
     check_bit_identical(cfg, specs, "megastep-device")
 
 
+@pytest.mark.parametrize("f64_exact", [True, False], ids=["device", "host"])
+def test_sink_ties_follow_scheduler_order(monkeypatch, f64_exact):
+    """Two lanes with long CR queues: events of different source ticks
+    reach the sink at the same instant.  The scheduler orders them by when
+    each arrival was scheduled (queue depth, then the arrival that armed
+    the queue), not by source tick; the latency lists must keep its order
+    on both backends."""
+    import jax
+
+    from repro.kernels.megastep import ops
+
+    monkeypatch.setitem(ops._X64_EXACT, jax.default_backend(), f64_exact)
+    cfg = ScenarioConfig(**{**BASE, "seed": 2, "num_va": 2, "num_cr": 2})
+    specs = [QuerySpec(tl="wbfs", tl_peak_speed=3.0 + i % 3) for i in range(4)]
+    if f64_exact:
+        check_bit_identical(cfg, specs, "megastep-device")
+    else:
+        check_bit_identical(cfg, specs, "megastep-host", "x64-emulated")
+
+
 # --------------------------------------------------------------------- #
 # Host backend (object TLs / overload divergence)                         #
 # --------------------------------------------------------------------- #
@@ -126,7 +146,7 @@ def test_host_fallback_on_overload():
         QuerySpec(tl="base"),
         QuerySpec(tl="wbfs", last_seen_camera=120),
     ]
-    check_bit_identical(cfg, specs, "megastep-host")
+    check_bit_identical(cfg, specs, "megastep-host", "device-capacity")
 
 
 def test_host_probabilistic_tl():
@@ -151,6 +171,77 @@ def test_host_kernel_spotlight_mode_full_duration():
     cfg = ScenarioConfig(**{**BASE, "tl": "wbfs"})
     specs = [QuerySpec(tl="wbfs"), QuerySpec(tl="wbfs", tl_peak_speed=3.0)]
     check_bit_identical(cfg, specs, "megastep-host", spotlight_mode="kernel")
+
+
+# --------------------------------------------------------------------- #
+# Device failures reach the host mirror loudly                            #
+# --------------------------------------------------------------------- #
+SHORT = {**BASE, "duration_s": 40.0}
+
+
+def test_device_error_falls_back_with_reason(monkeypatch):
+    """A device scan that raises hands the run to the host mirror, which
+    stays bit-identical, and the run says why."""
+    from repro.kernels.megastep import ops
+    from repro.obs import probe_backend
+
+    def broken_chunk(*args, **kwargs):
+        raise RuntimeError("injected device failure")
+
+    monkeypatch.setattr(ops, "_CHUNK_FN", broken_chunk)
+    # Restored at teardown: later runs in this process see a clean plane.
+    monkeypatch.setattr(ops, "_LAST_FALLBACK", ops._LAST_FALLBACK)
+    check_bit_identical(
+        ScenarioConfig(**SHORT), [QuerySpec(tl="wbfs")], "megastep-host",
+        "device-error: RuntimeError('injected device failure')",
+    )
+    name, ok, detail = probe_backend()
+    assert name == "backend" and not ok and "injected" in detail
+
+
+def test_cpu_f64_is_ieee_binary64():
+    from repro.kernels.megastep import ops
+
+    assert ops.x64_exact()
+
+
+def test_emulated_f64_refuses_device_with_reason(monkeypatch):
+    """Where f64 is not IEEE binary64 (a TPU lowers it to f32 pairs) the
+    bit-identity contract cannot hold on the device: the run goes to the
+    host mirror and records ``x64-emulated``."""
+    import jax
+
+    from repro.kernels.megastep import ops
+
+    monkeypatch.setitem(ops._X64_EXACT, jax.default_backend(), False)
+    check_bit_identical(
+        ScenarioConfig(**SHORT), [QuerySpec(tl="wbfs"), QuerySpec(tl="bfs")],
+        "megastep-host", "x64-emulated",
+    )
+
+
+def test_chip_smoke_refuses_without_tpu(tmp_path):
+    """``chip_smoke.py`` exits non-zero and prints no result line on the
+    CPU backend, and when it stands alone without the repo."""
+    import shutil
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    env.pop("PYTHONPATH", None)
+    shutil.copy(os.path.join(root, "chip_smoke.py"), tmp_path)
+    stderr = {}
+    for where, cwd in (("repo", root), ("alone", tmp_path)):
+        proc = subprocess.run(
+            [sys.executable, "chip_smoke.py"], cwd=cwd, env=env,
+            capture_output=True, text=True, timeout=300,
+        )
+        assert proc.returncode != 0, proc.stdout
+        assert '"ok"' not in proc.stdout
+        stderr[where] = proc.stderr
+    assert "needs a TPU, JAX found cpu" in stderr["repo"]
+    assert "No module named 'repro'" in stderr["alone"]
 
 
 # --------------------------------------------------------------------- #

@@ -9,8 +9,9 @@ per-summary, but deep-equal on every observable book.
 The compile-count half pins the dispatch contract: one world geometry run
 repeatedly (and chunked over multiple K-tick dispatches) compiles the scan
 at most once per bucket shape, the shape is accounted in
-``dispatch.jit_cache_sizes()``, and the Pallas lane-chain kernel
-(interpret mode off-TPU) is bit-equal to the jnp inner scan it replaces.
+``dispatch.jit_cache_sizes()``, and the Pallas lane-chain kernel (interpret
+mode: with f64 operands it compiles for no chip) is bit-equal to the jnp
+inner scan the engine runs.
 """
 
 import copy
@@ -64,8 +65,7 @@ def test_scan_compiles_once_per_bucket_shape():
                QuerySpec(tl="wbfs", last_seen_camera=11)]
 
     _, _, scn = _pair(_fixed_cfg(), specs_a)
-    if scn.engine_used != "megastep-device":  # pragma: no cover - no jax
-        pytest.skip(f"device backend unavailable: {scn.engine_used}")
+    assert scn.engine_used == "megastep-device", scn.engine_fallback_reason
     sizes0 = dispatch.jit_cache_sizes()["megastep"]
     assert sizes0 >= 1
 
@@ -91,25 +91,23 @@ def test_megastep_cache_is_bounded():
     padded kernel: its LRU is registered under the "megastep" key."""
     specs = [QuerySpec(tl="wbfs")]
     _, _, scn = _pair(_fixed_cfg(), specs)
-    if scn.engine_used != "megastep-device":  # pragma: no cover - no jax
-        pytest.skip(f"device backend unavailable: {scn.engine_used}")
+    assert scn.engine_used == "megastep-device", scn.engine_fallback_reason
     assert "megastep" in dispatch._JIT_LRU
     assert len(dispatch._JIT_LRU["megastep"]) <= dispatch.MAX_JIT_SHAPES
 
 
 # --------------------------------------------------------------------- #
-# Pallas lane-chain kernel == jnp inner scan (interpret mode off-TPU)     #
+# Pallas lane-chain kernel == jnp inner scan (interpret mode)             #
 # --------------------------------------------------------------------- #
 def test_pallas_lane_chain_matches_jnp_scan():
     jax = pytest.importorskip("jax")
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.kernels.megastep.kernel import lane_chain_tick_pallas
 
     rng = np.random.default_rng(7)
     L, S, U = 4, 8, 32
-    with enable_x64():
+    with jax.enable_x64(True):
         real = rng.random((L, S)) < 0.6
         has = rng.random((L, S)) < 0.5
         va_b = rng.uniform(0.0, 3.0, L)
@@ -126,7 +124,8 @@ def test_pallas_lane_chain_matches_jnp_scan():
             jnp.asarray(real), jnp.asarray(has), jnp.asarray(va_b),
             jnp.asarray(va_armed), jnp.asarray(cr_b), jnp.asarray(cr_armed),
             jnp.asarray(draws), jnp.asarray(uniforms), params,
-            interpret=jax.default_backend() != "tpu",
+            # f64 operands: the kernel compiles for no chip, only interprets.
+            interpret=True,
         )
 
         # The jnp reference: the exact slot_step scan from ops._build_chunk_fn.

@@ -115,7 +115,8 @@ SMALL_MESH_SCRIPT = textwrap.dedent(
     from repro.training import TrainConfig, init_adamw, make_train_step
 
     cfg = reduced_config(get_config("llama3.2-1b"))
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     rules = default_rules(mesh)
     with mesh, mesh_rules(rules):
         params = jax.eval_shape(lambda k: init_params(k, cfg), jax.random.PRNGKey(0))

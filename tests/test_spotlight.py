@@ -58,8 +58,8 @@ def test_csr_roundtrip(road):
 # Batched CSR relaxation == pure-Python Dijkstra (bit-exact in x64)      #
 # --------------------------------------------------------------------- #
 def test_spotlight_ball_ref_bit_exact_100_queries(road):
-    jnp = pytest.importorskip("jax.numpy")
-    from jax.experimental import enable_x64
+    jax = pytest.importorskip("jax")
+    import jax.numpy as jnp
 
     from repro.kernels.spotlight_ball.ref import dense_adjacency, spotlight_ball_ref
 
@@ -69,7 +69,7 @@ def test_spotlight_ball_ref_bit_exact_100_queries(road):
     sources = rng.integers(0, road.num_vertices, size=Q).astype(np.int32)
     radii = rng.uniform(50.0, 2000.0, size=Q)
 
-    with enable_x64():
+    with jax.enable_x64(True):
         W = jnp.asarray(dense_adjacency(indptr, indices, weights))
         D = np.asarray(spotlight_ball_ref(W, jnp.asarray(sources), jnp.asarray(radii)))
 

@@ -117,3 +117,29 @@ def test_auto_mode_resolution():
 def test_invalid_mode_rejected():
     with pytest.raises(ValueError):
         SweepRunner(mode="threads")
+
+
+def test_device_dispatching_cases_are_jax_touching():
+    """Every case that dispatches to a device counts, so auto mode keeps
+    it out of a fork pool: a chip takes one process."""
+    from repro.sim.sweep import QueryCase, _touches_jax
+
+    base = dict(num_cameras=100, duration_s=10.0, seed=0, tl="bfs")
+    assert not _touches_jax(ScenarioConfig(**base))
+    assert _touches_jax(ScenarioConfig(**base, embed_dim=16))
+    assert _touches_jax(ScenarioConfig(**base, engine="megastep"))
+    assert _touches_jax(QueryCase(2, ScenarioConfig(**base), spotlight_mode="kernel"))
+    assert not _touches_jax(QueryCase(2, ScenarioConfig(**base)))
+    mode, workers = SweepRunner(mode="auto")._resolve_mode(4, needs_jax=True)
+    assert (mode, workers) == ("serial", 1)
+
+
+@pytest.mark.skipif(not SweepRunner.fork_available(), reason="needs fork")
+def test_forced_fork_of_device_grid_refused_once_jax_is_up():
+    import jax
+
+    jax.devices()  # bring the backend up in this process
+    grid = [("mega", ScenarioConfig(num_cameras=100, duration_s=10.0, seed=0,
+                                    tl="bfs", engine="megastep"))]
+    with pytest.raises(RuntimeError, match="mode='serial'"):
+        SweepRunner(mode="fork").run(grid)
