@@ -5,14 +5,37 @@ designed so that, as long as the *source* and *sink* clocks agree
 (kappa_1 == kappa_n), a constant per-device skew ``sigma_i = kappa_i - kappa_1``
 cancels out of every drop and batch comparison.  We model that skew explicitly
 so the property tests can verify the cancellation.
+
+The module also owns host timing: :func:`monotonic` for wall-time reads
+and :func:`span` for the host spans a JAX profiler trace records.
 """
 
 from __future__ import annotations
 
+import contextlib
+import sys
 import time as _time
 from dataclasses import dataclass
 
-__all__ = ["Clock", "monotonic"]
+__all__ = ["Clock", "monotonic", "span", "SPANS", "MODULE_SPAN"]
+
+#: Every host span the platform emits, besides ``MODULE_SPAN + <module>``.
+SPANS = (
+    "repro.des.run",  # TrackingScenario.run_until: the event loop to t
+    "repro.des.drain",  # TrackingScenario.run: the event loop to the horizon
+    "repro.tl.tick",  # one TL tick: spotlights and control deltas
+    "repro.va.reid_build",  # VA re-ID: gallery stack and tenancy mask
+    "repro.va.reid_wait",  # VA re-ID: the host blocked on the answer
+    "repro.reid.dispatch",  # dispatch.reid_match_multi, whole
+    "repro.reid.prep",  # validation, padding, device-resident query lookup
+    "repro.reid.put",  # host-to-device puts of the per-call operands
+    "repro.reid.call",  # the jitted matcher's launch
+    "repro.reid.slice",  # slicing the padded answer to (N, Q)
+)
+#: Prefix of a module instance's user-logic span: ``task.module or task.name``.
+MODULE_SPAN = "repro.module."
+
+_NO_SPAN = contextlib.nullcontext()
 
 
 def monotonic() -> float:
@@ -27,6 +50,21 @@ def monotonic() -> float:
     honest than ``time.time()`` differences.
     """
     return _time.perf_counter()
+
+
+def span(name: str):
+    """A context manager marking a host span ``name`` in a JAX profiler
+    trace, on the same clock as the device's ops.
+
+    It reads no clock and keeps no state: where no profiler is running,
+    ``jax.profiler.TraceAnnotation`` records nothing.  Where JAX has not
+    been imported, no profiler can be running either, so a shared no-op
+    context is returned and JAX stays unimported.
+    """
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return _NO_SPAN
+    return jax.profiler.TraceAnnotation(name)
 
 
 @dataclass(slots=True)

@@ -459,8 +459,8 @@ class CompiledApp:
                 budget=TaskBudget(f"CR-{i}", cr_xi, m_max=self.cr_spec.m_max),
                 drops_enabled=drops,
                 node=node,
+                module="CR",
             )
-            t.module = "CR"
             t.output_event_bytes = 256.0  # metadata only (§2.2.3)
             t.connect(self.sink)
             t.partitioner = _constant_partitioner("UV")
@@ -495,8 +495,8 @@ class CompiledApp:
                 budget=TaskBudget(f"VA-{i}", va_xi, m_max=self.va_spec.m_max),
                 drops_enabled=drops,
                 node=node,
+                module="VA",
             )
-            t.module = "VA"
             for cr in self.cr_tasks:
                 t.connect(cr)
             t.partitioner = _table_partitioner(self._cr_route)
@@ -557,8 +557,8 @@ class CompiledApp:
             budget=TaskBudget(f"FC-{cam}", fc_xi, m_max=1),
             drops_enabled=self.deployment.drops_enabled,
             node=f"edge{cam}",
+            module="FC",
         )
-        t.module = "FC"
         for va in self.va_tasks:
             t.connect(va)
         # Each FC has a fixed key (its camera), so its destination VA is

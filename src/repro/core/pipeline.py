@@ -40,7 +40,7 @@ from typing import Any, Callable, Deque, Dict, List, Optional, Sequence, Tuple
 
 from .batching import DynamicBatcher, PendingEvent, StaticBatcher, _BatcherBase
 from .budget import TaskBudget
-from .clock import Clock
+from .clock import MODULE_SPAN, Clock, span
 from .dropping import drop_before_exec, drop_before_queuing, drop_before_transmit
 from .events import (
     AcceptSignal,
@@ -152,6 +152,7 @@ class Task:
         drops_enabled: bool = True,
         probe_every: int = 16,
         node: str = "",
+        module: str = "",
     ) -> None:
         self.name = name
         self.sim = sim
@@ -166,7 +167,9 @@ class Task:
         self.node = node or name
         # Which dataflow module type this task lowers (FC/VA/CR/UV, set by
         # the app compiler); empty for hand-wired tasks.
-        self.module: str = ""
+        self.module: str = module
+        # Host span around the user logic, named as EventTracer names hops.
+        self._span = MODULE_SPAN + (module or name)
         self.state: Dict[str, Any] = {}
         self.downstream: Dict[str, "Task"] = {}
         self.upstream: List["Task"] = []
@@ -465,7 +468,8 @@ class Task:
         stats.batches += 1
         stats.batch_sizes.append(1)
         h = ev.header
-        outputs = self.logic([ev], self.state)
+        with span(self._span):
+            outputs = self.logic([ev], self.state)
         u = arrival - h.source_arrival
         pi = 0.0 + exec_dur
         stats.executed += 1
@@ -498,7 +502,8 @@ class Task:
             pe = batch[0]
             ev = pe.event
             h = ev.header
-            outputs = self.logic([ev], self.state)
+            with span(self._span):
+                outputs = self.logic([ev], self.state)
             u = pe.arrival - h.source_arrival
             q = exec_start - pe.arrival
             pi = q + exec_dur
@@ -534,7 +539,8 @@ class Task:
         work: List[Event] = []
         for pe in batch:
             (probes if pe.event.header.is_probe else work).append(pe.event)
-        outputs = self.logic(work, self.state)
+        with span(self._span):
+            outputs = self.logic(work, self.state)
         if probes:
             outputs = list(outputs) + probes
         # Track the slowest event of the batch for the sink's accept logic.
@@ -883,7 +889,8 @@ class SinkTask(Task):
             if epsilon > self.epsilon_max:
                 self._send_accept(ev, epsilon=epsilon)
         if self.on_event is not None:
-            self.on_event(ev, now_local)
+            with span(self._span):
+                self.on_event(ev, now_local)
         # Flow ends here.  Recycling is only safe when the sink owner opted
         # in (``recycle_headers``): a user callback may have retained the
         # event, and we cannot detect that here.

@@ -37,7 +37,7 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
-from repro.core.clock import monotonic as _monotonic
+from repro.core.clock import span
 
 __all__ = [
     "BUCKET_MIN",
@@ -76,11 +76,8 @@ _STATS = {
 _SHAPES: set = set()
 
 # Observability profile (repro.obs.collect_dispatch): per-kernel distinct
-# bucket-shape compiles and accumulated host wall inside the dispatch entry
-# points.  Wall-clock reads go through core.clock.monotonic (DET002-clean)
-# and never feed any scheduling decision — attribution only.
+# bucket-shape compiles.
 _COMPILES: Dict[str, int] = {}
-_DISPATCH_WALL: Dict[str, float] = {}
 
 
 def bucket(n: int, minimum: int = BUCKET_MIN) -> int:
@@ -99,17 +96,14 @@ def reset_stats() -> None:
         _STATS[k] = 0
     _SHAPES.clear()
     _COMPILES.clear()
-    _DISPATCH_WALL.clear()
 
 
-def profile() -> Dict[str, Dict[str, float]]:
+def profile() -> Dict[str, Dict[str, int]]:
     """Kernel-plane profile: per-kernel distinct bucket-shape compile
-    counts (each new shape is one XLA compile of that kernel) and the
-    accumulated host wall spent inside the dispatch entry points."""
-    return {
-        "compiles": dict(_COMPILES),
-        "dispatch_wall_s": dict(_DISPATCH_WALL),
-    }
+    counts (each new shape is one XLA compile of that kernel).  Host time
+    inside the dispatch entry points is in the ``repro.reid.*`` spans of a
+    JAX profiler trace (``repro.core.clock.SPANS``)."""
+    return {"compiles": dict(_COMPILES)}
 
 
 def _note_shape(key: Tuple) -> None:
@@ -118,10 +112,6 @@ def _note_shape(key: Tuple) -> None:
         _STATS["bucket_shapes"] += 1
         name = str(key[0])
         _COMPILES[name] = _COMPILES.get(name, 0) + 1
-
-
-def _note_wall(name: str, t0: float) -> None:
-    _DISPATCH_WALL[name] = _DISPATCH_WALL.get(name, 0.0) + (_monotonic() - t0)
 
 
 # Per-kernel LRU of live bucket shapes, bounding the jit caches.
@@ -329,7 +319,6 @@ def spotlight_ball(indptr, indices, weights, sources, radii, *, dtype=np.float32
     key = ("ball", int(W.shape[0]), qb, np.dtype(dtype).str, use_pallas)
     _note_shape(key)
     bound_jit_cache("ball", _BALL_PADDED, key)
-    t0 = _monotonic()
     out = _BALL_PADDED(
         W,
         jnp.asarray(src_pad),
@@ -337,7 +326,6 @@ def spotlight_ball(indptr, indices, weights, sources, radii, *, dtype=np.float32
         use_pallas=use_pallas,
         interpret=interpret,
     )
-    _note_wall("ball", t0)
     return out[:Q]
 
 
@@ -422,11 +410,9 @@ def reid_match(gallery, queries, *, threshold: float = 0.5):
     key = ("reid", nb, qb, D)
     _note_shape(key)
     bound_jit_cache("reid", _REID_PADDED, key)
-    t0 = _monotonic()
     scores, best, matched = _REID_PADDED(
         jnp.asarray(g_pad), q_dev, jnp.int32(Q), jnp.float32(threshold)
     )
-    _note_wall("reid", t0)
     return scores[:N], best[:N], matched[:N]
 
 
@@ -477,55 +463,62 @@ def reid_match_multi(gallery, queries, *, mask=None, threshold: float = 0.5):
     multi-query VA stage relies on this to stay bit-identical to N
     independent single-query runs.
     """
+    with span("repro.reid.dispatch"):
+        return _reid_match_multi(gallery, queries, mask, threshold)
+
+
+def _reid_match_multi(gallery, queries, mask, threshold):
     global _REID_MULTI_PADDED
     import jax.numpy as jnp
 
-    _STATS["reid_multi_calls"] += 1
-    gallery = np.asarray(gallery, dtype=np.float32)
-    if gallery.ndim != 2:
-        raise ValueError(f"gallery must be (N, D), got {gallery.shape}")
-    N, D = gallery.shape
-    queries_np = np.asarray(queries, dtype=np.float32)
-    if queries_np.ndim != 2 or queries_np.shape[1] != D:
-        raise ValueError(f"queries must be (Q, {D}), got {queries_np.shape}")
-    Q = queries_np.shape[0]
-    if mask is None:
-        mask_np = np.ones((N, Q), dtype=bool)
-    else:
-        mask_np = np.asarray(mask, dtype=bool)
-        if mask_np.shape != (N, Q):
-            raise ValueError(f"mask must be ({N}, {Q}), got {mask_np.shape}")
-    nb, qb = bucket(N), bucket(Q)
-    g_pad = np.zeros((nb, D), dtype=np.float32)
-    g_pad[:N] = gallery
-    m_pad = np.zeros((nb, qb), dtype=bool)
-    m_pad[:N, :Q] = mask_np
+    with span("repro.reid.prep"):
+        _STATS["reid_multi_calls"] += 1
+        gallery = np.asarray(gallery, dtype=np.float32)
+        if gallery.ndim != 2:
+            raise ValueError(f"gallery must be (N, D), got {gallery.shape}")
+        N, D = gallery.shape
+        queries_np = np.asarray(queries, dtype=np.float32)
+        if queries_np.ndim != 2 or queries_np.shape[1] != D:
+            raise ValueError(f"queries must be (Q, {D}), got {queries_np.shape}")
+        Q = queries_np.shape[0]
+        if mask is None:
+            mask_np = np.ones((N, Q), dtype=bool)
+        else:
+            mask_np = np.asarray(mask, dtype=bool)
+            if mask_np.shape != (N, Q):
+                raise ValueError(f"mask must be ({N}, {Q}), got {mask_np.shape}")
+        nb, qb = bucket(N), bucket(Q)
+        g_pad = np.zeros((nb, D), dtype=np.float32)
+        g_pad[:N] = gallery
+        m_pad = np.zeros((nb, qb), dtype=bool)
+        m_pad[:N, :Q] = mask_np
 
-    def _pad_queries(_q):
-        q_pad = np.zeros((qb, D), dtype=np.float32)
-        q_pad[:Q] = queries_np
-        return q_pad
+        def _pad_queries(_q):
+            q_pad = np.zeros((qb, D), dtype=np.float32)
+            q_pad[:Q] = queries_np
+            return q_pad
 
-    if isinstance(queries, np.ndarray):
-        # The live-query block is long-lived (the query registry caches one
-        # array per live set): pad once, keep device-resident by identity —
-        # same contract as the single-query reid_match query block.
-        q_dev = _device_resident(queries, transform=_pad_queries)
-    else:
-        q_dev = jnp.asarray(_pad_queries(queries_np))
+        if isinstance(queries, np.ndarray):
+            # The live-query block is long-lived (the query registry caches
+            # one array per live set): pad once, keep device-resident by
+            # identity — same contract as the single-query reid_match block.
+            q_dev = _device_resident(queries, transform=_pad_queries)
+        else:
+            q_dev = jnp.asarray(_pad_queries(queries_np))
 
-    if _REID_MULTI_PADDED is None:
-        _REID_MULTI_PADDED = _make_reid_multi_padded()
-    key = ("reid_multi", nb, qb, D)
-    _note_shape(key)
-    bound_jit_cache("reid_multi", _REID_MULTI_PADDED, key)
-    t0 = _monotonic()
-    scores, matched = _REID_MULTI_PADDED(
-        jnp.asarray(g_pad), q_dev, jnp.asarray(m_pad),
-        jnp.float32(threshold),
-    )
-    _note_wall("reid_multi", t0)
-    return scores[:N, :Q], matched[:N, :Q]
+        if _REID_MULTI_PADDED is None:
+            _REID_MULTI_PADDED = _make_reid_multi_padded()
+        key = ("reid_multi", nb, qb, D)
+        _note_shape(key)
+        bound_jit_cache("reid_multi", _REID_MULTI_PADDED, key)
+    with span("repro.reid.put"):
+        g_dev = jnp.asarray(g_pad)
+        m_dev = jnp.asarray(m_pad)
+        thr = jnp.float32(threshold)
+    with span("repro.reid.call"):
+        scores, matched = _REID_MULTI_PADDED(g_dev, q_dev, m_dev, thr)
+    with span("repro.reid.slice"):
+        return scores[:N, :Q], matched[:N, :Q]
 
 
 def jit_cache_sizes() -> Dict[str, int]:

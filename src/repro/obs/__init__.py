@@ -1,5 +1,11 @@
 """Unified observability plane: metrics registry, span tracing, exporters.
 
+Two kinds of span: ``EventTracer`` follows sampled events through the
+pipeline in simulated time; ``span`` (from ``repro.core.clock``, where hot
+paths import it) marks host work at each layer boundary in any JAX
+profiler trace of a run, on the device trace's clock.  ``SPANS`` and
+``MODULE_SPAN`` name every host span the platform emits.
+
 Layering: ``repro.obs`` may import any other repro package (it observes
 them); nothing on a hot path imports ``repro.obs`` — the pipeline's
 tracer hooks are duck-typed and default to ``None``.
@@ -13,6 +19,7 @@ tracer hooks are duck-typed and default to ``None``.
     print(reg.digest())              # sha256 of the SIM-domain exposition
 """
 
+from repro.core.clock import MODULE_SPAN, SPANS, span
 from repro.obs.collectors import (
     collect_dispatch,
     collect_engine,
@@ -50,6 +57,9 @@ __all__ = [
     "MetricsRegistry",
     "EventTracer",
     "Span",
+    "span",
+    "SPANS",
+    "MODULE_SPAN",
     "transit_class",
     "collect_scenario",
     "collect_query_result",

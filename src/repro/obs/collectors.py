@@ -359,8 +359,9 @@ def collect_stage(registry: MetricsRegistry, stage,
 
 
 def collect_dispatch(registry: MetricsRegistry) -> MetricsRegistry:
-    """Kernel-plane profile: call/compile counters, jit cache occupancy and
-    accumulated dispatch wall (WALL: host timing + backend-dependent)."""
+    """Kernel-plane profile: call/compile counters and jit cache occupancy
+    (WALL: backend-dependent).  Host time inside the dispatch entry points
+    is in the ``repro.reid.*`` spans of a JAX profiler trace."""
     from repro.kernels import dispatch
 
     stats = dispatch.stats()
@@ -394,16 +395,6 @@ def collect_dispatch(registry: MetricsRegistry) -> MetricsRegistry:
     for kernel, n in sorted(profile["compiles"].items()):
         if n:
             compiles.inc(n, kernel=kernel)
-    wall = registry.counter(
-        "repro_kernel_dispatch_seconds_total",
-        "Accumulated host wall inside kernel dispatch entry points "
-        "(core.clock.monotonic).",
-        labels=("kernel",),
-        domain=WALL,
-    )
-    for kernel, s in sorted(profile["dispatch_wall_s"].items()):
-        if s:
-            wall.inc(s, kernel=kernel)
     sizes = registry.gauge(
         "repro_jit_cache_entries",
         "Entries currently held by each bounded jit cache.",

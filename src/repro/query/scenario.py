@@ -60,6 +60,7 @@ if TYPE_CHECKING:  # pragma: no cover - typing only (avoids the jax-heavy
 import numpy as np
 
 from repro.core.budget import TaskBudget
+from repro.core.clock import span
 from repro.core.events import Event
 from repro.core.pipeline import DP_FAULT
 from repro.core.tracking import TLProbabilistic, TLWBFS, multi_source_spotlight
@@ -374,36 +375,37 @@ class MultiQueryScenario(TrackingScenario):
     # TL plane: per-query spotlights, one union control delta             #
     # ------------------------------------------------------------------ #
     def _tl_tick(self) -> None:  # overrides TrackingScenario
-        now = self.sim.time
-        dets = self._pending_detections
-        masks = self._pending_masks
-        self._pending_detections = []
-        self._pending_masks = []
-        live = self.registry.live_states()
-        targets = self._query_targets(live, dets, masks, now)
-        lat = self.sim.network.man_latency_s
-        sched = self.sim.schedule
-        union: Set[int] = set()
-        for st, new_active in zip(live, targets):
-            st.active_timeline.append((now, len(new_active)))
-            prev = st.requested
-            for cam in new_active - prev:
-                sched(lat, self._apply_query_active, st.query_id, cam, True)
-            for cam in prev - new_active:
-                sched(lat, self._apply_query_active, st.query_id, cam, False)
-            st.requested = new_active
-            union |= new_active
-        self._stats_active.append((now, len(union)))
-        prev = self._ctrl_target
-        set_active = self.compiled.set_fc_active
-        for cam in union - prev:
-            sched(lat, set_active, cam, True)
-        for cam in prev - union:
-            sched(lat, set_active, cam, False)
-        self._ctrl_target = union
-        self._drain_admission_queue()
-        if now + self.cfg.tl_update_period <= self.cfg.duration_s:
-            self.sim.schedule(self.cfg.tl_update_period, self._tl_tick)
+        with span("repro.tl.tick"):
+            now = self.sim.time
+            dets = self._pending_detections
+            masks = self._pending_masks
+            self._pending_detections = []
+            self._pending_masks = []
+            live = self.registry.live_states()
+            targets = self._query_targets(live, dets, masks, now)
+            lat = self.sim.network.man_latency_s
+            sched = self.sim.schedule
+            union: Set[int] = set()
+            for st, new_active in zip(live, targets):
+                st.active_timeline.append((now, len(new_active)))
+                prev = st.requested
+                for cam in new_active - prev:
+                    sched(lat, self._apply_query_active, st.query_id, cam, True)
+                for cam in prev - new_active:
+                    sched(lat, self._apply_query_active, st.query_id, cam, False)
+                st.requested = new_active
+                union |= new_active
+            self._stats_active.append((now, len(union)))
+            prev = self._ctrl_target
+            set_active = self.compiled.set_fc_active
+            for cam in union - prev:
+                sched(lat, set_active, cam, True)
+            for cam in prev - union:
+                sched(lat, set_active, cam, False)
+            self._ctrl_target = union
+            self._drain_admission_queue()
+            if now + self.cfg.tl_update_period <= self.cfg.duration_s:
+                self.sim.schedule(self.cfg.tl_update_period, self._tl_tick)
 
     def _query_targets(
         self, live: List[QueryState], dets, masks, now: float
@@ -565,25 +567,27 @@ class MultiQueryScenario(TrackingScenario):
     def _va_reid(self, events: List[Event], state: Dict) -> None:
         from repro.kernels import dispatch
 
-        block, block_states = self.registry.embedding_block()
-        if not block_states:
-            return
-        embs = [getattr(ev.value, "embedding", None) for ev in events]
-        idx = [i for i, e in enumerate(embs) if e is not None]
-        if not idx:
-            return
-        gallery = np.stack([embs[i] for i in idx])
-        nq = len(block_states)
-        mask = np.zeros((len(idx), nq), dtype=bool)
-        for row, i in enumerate(idx):
-            m = events[i].query_mask
-            for col, st in enumerate(block_states):
-                if m & st.bit:
-                    mask[row, col] = True
+        with span("repro.va.reid_build"):
+            block, block_states = self.registry.embedding_block()
+            if not block_states:
+                return
+            embs = [getattr(ev.value, "embedding", None) for ev in events]
+            idx = [i for i, e in enumerate(embs) if e is not None]
+            if not idx:
+                return
+            gallery = np.stack([embs[i] for i in idx])
+            nq = len(block_states)
+            mask = np.zeros((len(idx), nq), dtype=bool)
+            for row, i in enumerate(idx):
+                m = events[i].query_mask
+                for col, st in enumerate(block_states):
+                    if m & st.bit:
+                        mask[row, col] = True
         _, matched = dispatch.reid_match_multi(
             gallery, block, mask=mask, threshold=self.cfg.reid_threshold
         )
-        matched = np.asarray(matched)
+        with span("repro.va.reid_wait"):
+            matched = np.asarray(matched)
         avoid = self.deployment.avoid_drop_positives
         for row, i in enumerate(idx):
             hit = False

@@ -37,6 +37,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
+from repro.core.clock import span
 from repro.core.compile import (
     CompiledApp,
     DeploymentSpec,
@@ -605,23 +606,24 @@ class TrackingScenario:
         self._pending_detections.append(det)
 
     def _tl_tick(self) -> None:
-        now = self.sim.time
-        dets, self._pending_detections = self._pending_detections, []
-        new_active = self.tl.update(dets, now)
-        self._stats_active.append((now, len(new_active)))
-        # Control events to FCs (TL -> FC, §2.2.1) after a control latency.
-        # Only the delta against the previously requested set is scheduled,
-        # so a tick costs O(|changed|), not O(num_cameras).
-        latency = self.sim.network.man_latency_s
-        set_active = self.compiled.set_fc_active
-        prev = self._ctrl_target
-        for cam in new_active - prev:
-            self.sim.schedule(latency, set_active, cam, True)
-        for cam in prev - new_active:
-            self.sim.schedule(latency, set_active, cam, False)
-        self._ctrl_target = new_active
-        if now + self.cfg.tl_update_period <= self.cfg.duration_s:
-            self.sim.schedule(self.cfg.tl_update_period, self._tl_tick)
+        with span("repro.tl.tick"):
+            now = self.sim.time
+            dets, self._pending_detections = self._pending_detections, []
+            new_active = self.tl.update(dets, now)
+            self._stats_active.append((now, len(new_active)))
+            # Control events to FCs (TL -> FC, §2.2.1) after a control latency.
+            # Only the delta against the previously requested set is scheduled,
+            # so a tick costs O(|changed|), not O(num_cameras).
+            latency = self.sim.network.man_latency_s
+            set_active = self.compiled.set_fc_active
+            prev = self._ctrl_target
+            for cam in new_active - prev:
+                self.sim.schedule(latency, set_active, cam, True)
+            for cam in prev - new_active:
+                self.sim.schedule(latency, set_active, cam, False)
+            self._ctrl_target = new_active
+            if now + self.cfg.tl_update_period <= self.cfg.duration_s:
+                self.sim.schedule(self.cfg.tl_update_period, self._tl_tick)
 
     # ------------------------------------------------------------------ #
     # Frame generation                                                    #
@@ -811,14 +813,16 @@ class TrackingScenario:
         without finalizing — the serving plane uses this to model a driver
         process that is killed mid-run, and ``run()`` continues from here."""
         self._schedule_ticks()
-        self.sim.run(until=min(t, self._horizon))
+        with span("repro.des.run"):
+            self.sim.run(until=min(t, self._horizon))
 
     # ------------------------------------------------------------------ #
     def run(self) -> ScenarioResult:
         cfg = self.cfg
         self._schedule_ticks()
         # Allow in-flight events to drain past the generation horizon.
-        self.sim.run(until=self._horizon)
+        with span("repro.des.drain"):
+            self.sim.run(until=self._horizon)
 
         if self._trace is not None:
             # Final sample after the drain: cumulative counters (drops,

@@ -1,0 +1,113 @@
+"""Host spans (``repro.core.clock.span``): where the platform marks its
+layer boundaries in a JAX profiler trace, and what the helper costs a
+process that never imports JAX."""
+
+import glob
+import os
+import subprocess
+import sys
+
+import pytest
+
+from repro.core.clock import MODULE_SPAN, SPANS
+from repro.kernels import dispatch
+from repro.query import MultiQueryScenario, QuerySpec
+from repro.sim import ScenarioConfig
+
+SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
+
+
+def _inside(inner, outer):
+    return outer[0] <= inner[0] and inner[1] <= outer[1]
+
+
+def _parent(iv, spans, pred):
+    """The innermost span matching ``pred`` that holds ``iv``."""
+    held = [s for s in spans if pred(s[2]) and s is not iv and _inside(iv, s)]
+    return min(held, key=lambda s: s[1] - s[0]) if held else None
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """A small re-ID deployment stepped one frame period at a time under a
+    CPU profiler trace: its ``repro.*`` host spans and the dispatch count."""
+    import jax
+    from jax.profiler import ProfileData
+
+    cfg = ScenarioConfig(num_cameras=200, duration_s=12.0, seed=0, tl="wbfs",
+                         batching="dynamic", m_max=25, embed_dim=16)
+    scn = MultiQueryScenario(cfg, [QuerySpec(), QuerySpec(embedding_seed=99)])
+    scn.run_until(1.0)  # compile outside the trace
+    out = str(tmp_path_factory.mktemp("trace"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    dispatch.reset_stats()
+    jax.profiler.start_trace(out, profiler_options=opts)
+    try:
+        for k in range(2, 13):
+            scn.run_until(float(k))
+    finally:
+        jax.profiler.stop_trace()
+    calls = dispatch.stats()["reid_multi_calls"]
+    (path,) = glob.glob(os.path.join(out, "**", "*.xplane.pb"), recursive=True)
+    spans = [(e.start_ns, e.start_ns + e.duration_ns, e.name)
+             for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for e in line.events
+             if e.name.startswith("repro.")]
+    return spans, calls
+
+
+def test_every_span_name_is_in_the_closed_list(traced):
+    spans, _ = traced
+    names = {n for _, _, n in spans}
+    assert names, "no repro.* span in the trace"
+    assert all(n in SPANS or n.startswith(MODULE_SPAN) for n in names), names
+    assert all(n.startswith("repro.") for n in SPANS)
+    assert {MODULE_SPAN + m for m in ("VA", "CR", "UV")} <= names
+
+
+def test_each_step_is_one_des_run_span(traced):
+    spans, _ = traced
+    assert sum(n == "repro.des.run" for _, _, n in spans) == 11
+    ticks = [s for s in spans if s[2] == "repro.tl.tick"]
+    assert ticks and all(_parent(t, spans, lambda n: n == "repro.des.run") for t in ticks)
+
+
+def test_dispatch_sits_in_module_logic_in_a_des_step(traced):
+    spans, calls = traced
+    disp = [s for s in spans if s[2] == "repro.reid.dispatch"]
+    assert calls > 0 and len(disp) == calls
+    for d in disp:
+        module = _parent(d, spans, lambda n: n.startswith(MODULE_SPAN))
+        assert module is not None and module[2] == MODULE_SPAN + "VA"
+        assert _parent(module, spans, lambda n: n == "repro.des.run") is not None
+        parts = [s[2] for s in spans if s[2].startswith("repro.reid.")
+                 and s is not d and _inside(s, d)]
+        assert parts == ["repro.reid.prep", "repro.reid.put", "repro.reid.call",
+                         "repro.reid.slice"]
+    for name in ("repro.va.reid_build", "repro.va.reid_wait"):
+        mine = [s for s in spans if s[2] == name]
+        assert len(mine) == calls
+        assert all(_parent(s, spans, lambda n: n == MODULE_SPAN + "VA") for s in mine)
+
+
+def test_span_leaves_jax_unimported():
+    code = ("import sys\n"
+            "from repro.core.clock import span\n"
+            "with span('repro.des.run'):\n"
+            "    pass\n"
+            "assert 'jax' not in sys.modules, 'span imported jax'\n"
+            "print('NO_JAX_OK')\n")
+    res = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True,
+                         env={**os.environ, "PYTHONPATH": SRC}, timeout=120)
+    assert "NO_JAX_OK" in res.stdout, res.stdout + res.stderr
+
+
+def test_observability_plane_names_the_same_spans():
+    import repro.obs as obs
+    from repro.core import clock
+
+    assert obs.span is clock.span and obs.SPANS is SPANS and obs.MODULE_SPAN == MODULE_SPAN
+    text = obs.prometheus_exposition(obs.collect_dispatch(obs.MetricsRegistry()))
+    assert "repro_kernel_dispatch_seconds_total" not in text
