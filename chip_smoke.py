@@ -233,10 +233,13 @@ def phase_a(seed: int) -> None:
     real = dispatch.reid_match_multi
     platforms = set()
 
-    def on_chip(*a, **kw):
-        scores, matched = real(*a, **kw)
-        platforms.update(d.platform for d in matched.devices())
-        return scores, matched
+    def on_chip(gallery, queries, **kw):
+        answer = real(gallery, queries, **kw)
+        # The answer comes back on the host; the matcher ran on the device
+        # that holds the query block it was handed.
+        _, block = dispatch._DEVICE_CACHE[id(queries)]
+        platforms.update(d.platform for d in block.devices())
+        return answer
 
     with phase("A reid_match_multi") as fields:
         fields.update(cameras=cams, duration_s=dur, queries=QUERIES,

@@ -25,12 +25,11 @@ SPANS = (
     "repro.des.drain",  # TrackingScenario.run: the event loop to the horizon
     "repro.tl.tick",  # one TL tick: spotlights and control deltas
     "repro.va.reid_build",  # VA re-ID: gallery stack and tenancy mask
-    "repro.va.reid_wait",  # VA re-ID: the host blocked on the answer
+    "repro.va.reid_wait",  # VA re-ID: reading the answer, which the dispatch returns on the host
     "repro.reid.dispatch",  # dispatch.reid_match_multi, whole
     "repro.reid.prep",  # validation, padding, device-resident query lookup
-    "repro.reid.put",  # host-to-device puts of the per-call operands
-    "repro.reid.call",  # the jitted matcher's launch
-    "repro.reid.slice",  # slicing the padded answer to (N, Q)
+    "repro.reid.call",  # the jitted matcher's launch, host operands' transfer included
+    "repro.reid.slice",  # the padded answer read to the host and cut to (N, Q)
 )
 #: Prefix of a module instance's user-logic span: ``task.module or task.name``.
 MODULE_SPAN = "repro.module."

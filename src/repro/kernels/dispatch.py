@@ -17,7 +17,10 @@ This layer makes kernel launches sweep-friendly:
 * **device-resident operands** — the dense min-plus adjacency of a road
   network and re-id query blocks are uploaded once and cached by operand
   identity (weakly referenced, so a dropped world frees its buffers).
-  Per-call padded scratch operands are donated to the kernel.
+  Per-call padded scratch operands of the single-query and spotlight
+  kernels are donated to the kernel.  ``reid_match_multi`` makes one device
+  launch a call: its scratch operands go into the jitted call as host
+  arrays, and its padded answer comes back to the host and is cut there.
 * **cache-miss accounting** — :func:`stats` counts calls and distinct
   bucket shapes, and :func:`jit_cache_sizes` exposes the underlying jit
   caches so tests can assert "at most one compile per bucket shape".
@@ -423,9 +426,7 @@ def _make_reid_multi_padded():
     import jax
     import jax.numpy as jnp
 
-    donate = (0, 2) if jax.default_backend() == "tpu" else ()
-
-    @functools.partial(jax.jit, donate_argnums=donate)
+    @jax.jit
     def reid_multi_padded(gallery, queries, mask, threshold):
         # Per-(candidate, query) cosine similarity with a broadcast
         # multiply-then-reduce over the feature axis: every sim[n, q] is an
@@ -455,6 +456,11 @@ def reid_match_multi(gallery, queries, *, mask=None, threshold: float = 0.5):
     ``-inf`` score and ``matched=False``.  Both axes are padded to
     power-of-two buckets (pad pairs masked out), so a whole multi-query
     sweep compiles this kernel at most once per bucket shape.
+
+    One device launch per call: the padded gallery, mask and threshold go
+    into the jitted call as host operands, and the padded answer is copied
+    back and cut to ``(N, Q)`` on the host, so the answers are host
+    ``np.ndarray`` views (float32 scores, bool flags), read-only.
 
     Bit-exactness contract: each ``sim[n, q]`` is an independent
     normalize-then-reduce over ``D``, so real entries are **bitwise** equal
@@ -511,14 +517,14 @@ def _reid_match_multi(gallery, queries, mask, threshold):
         key = ("reid_multi", nb, qb, D)
         _note_shape(key)
         bound_jit_cache("reid_multi", _REID_MULTI_PADDED, key)
-    with span("repro.reid.put"):
-        g_dev = jnp.asarray(g_pad)
-        m_dev = jnp.asarray(m_pad)
-        thr = jnp.float32(threshold)
     with span("repro.reid.call"):
-        scores, matched = _REID_MULTI_PADDED(g_dev, q_dev, m_dev, thr)
+        # A host f32 scalar keeps the threshold's abstract value f32[].
+        thr = np.float32(threshold)
+        scores, matched = _REID_MULTI_PADDED(g_pad, q_dev, m_pad, thr)
     with span("repro.reid.slice"):
-        return scores[:N, :Q], matched[:N, :Q]
+        scores.copy_to_host_async()
+        matched.copy_to_host_async()
+        return np.asarray(scores)[:N, :Q], np.asarray(matched)[:N, :Q]
 
 
 def jit_cache_sizes() -> Dict[str, int]:

@@ -203,6 +203,57 @@ def test_reid_multi_buckets_and_compile_accounting():
     assert dispatch.stats()["reid_multi_calls"] == before + 6
 
 
+def test_reid_multi_one_program_per_call_and_host_answers():
+    """Once a bucket is warm, a call with any other (N, Q) pair in it
+    compiles nothing: the matcher is the one program a dispatch runs, and
+    the answer is cut to (N, Q) on the host.  The answers are host arrays
+    bit-equal to the padded kernel's output cut there."""
+    import jax
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(14)
+    D, thr = 44, 0.05  # D private to this test: its bucket starts cold
+    pairs = ((1, 9), (2, 16), (7, 9), (8, 13), (5, 12))  # one (8, 16) bucket
+    calls = []
+    for N, Q in pairs:
+        calls.append((rng.normal(size=(N, D)).astype(np.float32),
+                      rng.normal(size=(Q, D)).astype(np.float32),
+                      rng.random((N, Q)) < 0.7))
+    dispatch.reid_match_multi(*calls[0][:2], mask=calls[0][2], threshold=thr)
+
+    compiles = []
+
+    def on_duration(event, secs, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(secs)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        answers = [dispatch.reid_match_multi(g, q, mask=m, threshold=thr)
+                   for g, q, m in calls[1:]]
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    assert compiles == []
+
+    for (g, q, m), (scores, matched) in zip(calls[1:], answers):
+        N, Q = m.shape
+        assert type(scores) is np.ndarray and type(matched) is np.ndarray
+        assert scores.dtype == np.float32 and matched.dtype == np.bool_
+        assert scores.shape == matched.shape == (N, Q)
+        g_pad = np.zeros((8, D), np.float32)
+        g_pad[:N] = g
+        q_pad = np.zeros((16, D), np.float32)
+        q_pad[:Q] = q
+        m_pad = np.zeros((8, 16), bool)
+        m_pad[:N, :Q] = m
+        want_s, want_m = dispatch._REID_MULTI_PADDED(
+            jnp.asarray(g_pad), jnp.asarray(q_pad), jnp.asarray(m_pad), jnp.float32(thr))
+        np.testing.assert_array_equal(scores, np.asarray(want_s)[:N, :Q])
+        np.testing.assert_array_equal(matched, np.asarray(want_m)[:N, :Q])
+    flags = np.concatenate([m.ravel() for _, m in answers])
+    assert flags.any() and not flags.all()
+
+
 def test_jit_cache_is_bounded(monkeypatch):
     """Sweeping more distinct bucket shapes than MAX_JIT_SHAPES must not
     grow a kernel's compile cache without bound: the LRU drops the cache on

@@ -84,8 +84,7 @@ def test_dispatch_sits_in_module_logic_in_a_des_step(traced):
         assert _parent(module, spans, lambda n: n == "repro.des.run") is not None
         parts = [s[2] for s in spans if s[2].startswith("repro.reid.")
                  and s is not d and _inside(s, d)]
-        assert parts == ["repro.reid.prep", "repro.reid.put", "repro.reid.call",
-                         "repro.reid.slice"]
+        assert parts == ["repro.reid.prep", "repro.reid.call", "repro.reid.slice"]
     for name in ("repro.va.reid_build", "repro.va.reid_wait"):
         mine = [s for s in spans if s[2] == name]
         assert len(mine) == calls
