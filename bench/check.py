@@ -4,9 +4,11 @@ Nothing here imports the platform; it reads the books the platform keeps.
 Four numbers, each with its limit (``LIMITS``):
 
 * ``replays_differing`` — replays of the window (the one the window cut
-  short included, as far as it got) whose books differ from the plain
-  simulator's (``bench/refsim.py``) in any count, state, spotlight size,
-  camera set, re-ID dispatch or re-ID gallery row.  Limit 0.
+  short included, as far as it got) whose books differ from those of the
+  configuration's plain reference (``bench/refsim.py`` unless it names
+  another) in any module's arrivals, executions, batches or drops at each
+  drop point, any count, drop, state, spotlight size, camera set, re-ID
+  dispatch or re-ID gallery row.  Limit 0.
 * ``latency_gap_s`` — the widest gap between the platform's and the
   simulator's sink times and end-to-end latencies, and the times each query
   was found, over every replay.
@@ -40,7 +42,20 @@ LIMITS = {
 # --------------------------------------------------------------------- #
 _COUNTS = ("sourced", "positives_generated", "completed", "on_time", "delayed",
            "positives_completed", "detections_on_time", "orphan_completed",
-           "reid_matched")
+           "reid_matched", "dropped", "orphan_dropped")
+
+
+#: ``engine_used`` of a replay the fused engine ran to its end.
+ENGINE_RUNS = ("megastep-host", "megastep-device")
+
+
+def _module_books(tasks) -> Dict[str, int]:
+    """Drops at each point, arrivals, executions and batches, summed over
+    a module's instances; the drop points first, being causes."""
+    s = [t.stats for t in tasks]
+    return {"dp1": sum(x.dropped_dp1 for x in s), "dp2": sum(x.dropped_dp2 for x in s),
+            "dp3": sum(x.dropped_dp3 for x in s), "arrived": sum(x.arrived for x in s),
+            "executed": sum(x.executed for x in s), "batches": sum(x.batches for x in s)}
 
 
 def observe_platform(scn, res, calls: List[list]) -> Dict[str, Any]:
@@ -59,11 +74,22 @@ def observe_platform(scn, res, calls: List[list]) -> Dict[str, Any]:
          "reid_matched": base.reid_matched if base is not None else scn._reid_matched,
          "reid_dispatches": len(calls)}
     gallery = sorted(row.tobytes() for c in calls for row in np.asarray(c[1]))
-    exact = {"global": g, "timeline": list(scn._stats_active), "gallery": gallery,
-             "per": {}}
+    app = scn.compiled
+    if getattr(scn, "engine_used", "") in ENGINE_RUNS:
+        # The fused engine runs no module instance: it keeps each execution
+        # as a batch size of its result, and runs drops off only.
+        modules = {m: dict(dp1=0, dp2=0, dp3=0, arrived=sum(b), executed=sum(b),
+                           batches=len(b)) for m, b in base.batch_sizes.items()}
+    else:
+        modules = {"VA": _module_books(app.va_tasks), "CR": _module_books(app.cr_tasks)}
+        if app.fc_tasks:  # a fused FC stage keeps no instances
+            modules = dict(FC=_module_books(app.fc_tasks.values()), **modules)
+    exact = {"modules": modules, "per": {}, "global": g, "gallery": gallery,
+             "timeline": list(scn._stats_active)}
     timed = {"global": sorted(latencies), "per": {}}
     for qid, st in scn.registry.states.items():
         exact["per"][qid] = dict({k: getattr(st, k) for k in _COUNTS},
+                                 dp={f"dp{i}": st.dp[i] for i in (1, 2, 3)},
                                  state=st.state, ended_at=st.ended_at,
                                  found=st.found_at is not None,
                                  timeline=list(st.active_timeline),
@@ -76,11 +102,12 @@ def observe_platform(scn, res, calls: List[list]) -> Dict[str, Any]:
 
 def first_diff(a, b, path: str = ""):
     """Path and values of the first field where ``a`` and ``b`` differ
-    (None when equal)."""
+    (None when equal), in the order of ``a``'s keys: the books list causes
+    (drops, batches) before their effects."""
     if type(a) is type(b) and isinstance(a, (dict, list, tuple)) and a == b:
         return None
     if isinstance(a, dict) and isinstance(b, dict):
-        for k in sorted(set(a) | set(b), key=str):
+        for k in list(a) + [k for k in b if k not in a]:
             if k not in a or k not in b:
                 return f"{path}/{k}", a.get(k, "<missing>"), b.get(k, "<missing>")
             d = first_diff(a[k], b[k], f"{path}/{k}")

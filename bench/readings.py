@@ -16,19 +16,18 @@ import argparse
 import json
 import sys
 
-from . import check, refsim, run, workload
+from . import check, run, workload
 
 
 def time_control_gap(cell_name: str, seed: int) -> float:
-    """``latency_gap_s`` of the float32-time simulator against the float64
-    one, over a whole replay of the cell."""
+    """``latency_gap_s`` of the reference run in float32 time against its
+    float64 run, over a whole replay of the cell."""
     cell = workload.load_cell(cell_name)
-    world = refsim.World(cell.config["scenario"])
     plans = workload.query_plans(cell, seed)
     scn = cell.config["scenario"]
     horizon = scn["duration_s"] + 3.0 * scn["gamma"]
-    want = refsim.Reference(cell.config, world, plans).run_until(horizon).observe()
-    got = refsim.Reference(cell.config, world, plans, time32=True).run_until(horizon).observe()
+    want = run.reference_books(cell, {}, plans, [horizon])[horizon]
+    got = run.reference_books(cell, {}, plans, [horizon], time32=True)[horizon]
     return check.timed_gap(got["timed"], want["timed"])
 
 
@@ -45,7 +44,7 @@ def main(argv=None) -> int:
         try:
             out = run.run_cell(args.workload, seed, 0.0, False, matcher=matcher, whole=True,
                                log=lambda s: print(s, file=sys.stderr))
-        except run.NoChip as e:
+        except (run.NoChip, run.Refused) as e:
             print(f"bench: {e}", file=sys.stderr)
             return 2
         row = {"kind": kind, "seed": seed, "correct": out["correct"],

@@ -5,7 +5,8 @@ It follows the pipeline of arXiv 1902.05577 as the configuration states
 it: cameras on the road graph source one frame per frame period while some
 query's spotlight holds them; each frame goes FC -> VA -> CR -> sink, every
 module instance a FIFO server with the affine cost ``c0 + c1`` of a batch of
-one (drops off), every hop its network latency plus size over bandwidth;
+one (drops off, so dynamic batching never grows a batch), FC folded into the
+hop to VA, every hop its network latency plus size over bandwidth;
 VA runs re-ID of the frame's embedding against each live query's; CR gives
 a positive verdict on an entity frame with probability ``p_true_positive``;
 each frame period the tracking logic (TL-WBFS) contracts a query's
@@ -19,6 +20,10 @@ embedding draws) is the deployment's data, made from its seed by the same
 generator the deployment documents.  Time is float64; ``time32=True`` keeps
 every event time in float32 instead (the precision control of the time
 guarantee).  ``observe()`` gives the books as of the simulated time reached.
+
+As a reference module (``bench/run.py::reference_books``) it offers
+``refuses(config, plans)``, which names what the configuration asks for and
+this simulator does not model, and ``books(config, plans, cuts)``.
 """
 
 from __future__ import annotations
@@ -214,14 +219,22 @@ class Query:
         return self.state in ("scoped", "found")
 
 
+NO_DROPS = {"dp1": 0, "dp2": 0, "dp3": 0}
+
+
 class Server:
-    """One module instance: a FIFO server taking one frame at a time."""
+    """One module instance: a FIFO server taking one frame at a time.  Its
+    books count a frame served on arrival as executed when its service
+    starts, and a frame that queued when its service ends, as the platform
+    counts them."""
 
     def __init__(self, xi: float, node: str) -> None:
         self.xi, self.node = xi, node
         self.free_at = -math.inf
         self.queue: deque = deque()
         self.waking = False
+        self.arrived = 0
+        self.executed = 0
 
 
 class Reference:
@@ -270,8 +283,6 @@ class Reference:
         self.now = 0.0
         self._heap: List[tuple] = []
         self._seq = 0
-        if any(p["tl"] != "wbfs" for p in plans):
-            raise ValueError("the reference simulates TL-WBFS queries only")
         self.queries = [Query(i, p) for i, p in enumerate(plans)]
         self.mask_of: Dict[int, int] = {}
         self.lit: set = set()          # cameras the control plane has switched on
@@ -417,7 +428,9 @@ class Reference:
             self.arrive(self.va[lane], f, self.va_done)
 
     def arrive(self, srv: Server, f: dict, done) -> None:
+        srv.arrived += 1
         if not srv.queue and self.now >= srv.free_at:
+            srv.executed += 1
             self.serve(srv, f, done)
             return
         srv.queue.append(f)
@@ -428,9 +441,13 @@ class Reference:
     def wake(self, srv: Server, done) -> None:
         srv.waking = False
         self.serve(srv, srv.queue.popleft(), done)
+        self.at(srv.free_at, self.finish, srv)
         if srv.queue:
             srv.waking = True
             self.at(srv.free_at, self.wake, srv, done)
+
+    def finish(self, srv: Server) -> None:
+        srv.executed += 1
 
     def serve(self, srv: Server, f: dict, done) -> None:
         srv.free_at = self.rnd(self.now + srv.xi)
@@ -488,12 +505,20 @@ class Reference:
     # ---- the books ------------------------------------------------------- #
     def observe(self) -> Dict[str, Any]:
         """Books as of the simulated time reached: ``exact`` must equal the
-        platform's field for field, ``timed`` within the latency limit."""
-        exact = {"global": dict(self.g), "timeline": list(self.g_timeline),
-                 "gallery": sorted(self.gallery), "per": {}}
+        platform's field for field, ``timed`` within the latency limit.
+        Every frame a server executed was a batch of its own, and nothing is
+        dropped."""
+        modules = {}
+        for name, servers in (("VA", self.va), ("CR", self.cr)):
+            done = sum(s.executed for s in servers)
+            modules[name] = dict(NO_DROPS, arrived=sum(s.arrived for s in servers),
+                                 executed=done, batches=done)
+        exact = {"modules": modules, "per": {}, "global": dict(self.g),
+                 "gallery": sorted(self.gallery), "timeline": list(self.g_timeline)}
         timed = {"global": sorted(self.g_latencies), "per": {}}
         for q in self.queries:
-            exact["per"][q.qid] = dict(q.n, state=q.state, ended_at=q.ended_at,
+            exact["per"][q.qid] = dict(q.n, dropped=0, orphan_dropped=0, dp=dict(NO_DROPS),
+                                       state=q.state, ended_at=q.ended_at,
                                        found=q.found_at is not None,
                                        timeline=list(q.timeline),
                                        requested=sorted(q.requested),
@@ -501,3 +526,47 @@ class Reference:
             timed["per"][q.qid] = sorted(q.latencies) + (
                 [(q.found_at, 0.0)] if q.found_at is not None else [])
         return {"exact": exact, "timed": timed}
+
+
+# --------------------------------------------------------------------- #
+# The reference contract                                                  #
+# --------------------------------------------------------------------- #
+#: Scenario keys the simulator reads, or knows to change nothing in what it
+#: simulates (``m_max``, ``epsilon_max`` and ``static_batch`` with drops off
+#: and dynamic batching; ``tl``, which the query plans override).
+KNOWN = {"num_cameras", "duration_s", "fps", "entity_speed_mps", "fov_radius_m", "seed",
+         "road_vertices", "gamma", "epsilon_max", "tl", "tl_update_period",
+         "tl_min_radius_m", "batching", "static_batch", "m_max", "drops_enabled",
+         "avoid_drop_positives", "num_va", "num_cr", "num_nodes", "fc_cost", "va_cost",
+         "cr_cost", "p_true_positive", "embed_dim", "reid_threshold"}
+
+
+def refuses(config: Dict[str, Any], plans: Sequence[Dict]) -> Optional[str]:
+    """Why this simulator cannot judge ``config`` under ``plans``, or None."""
+    scn = config["scenario"]
+    why = []
+    tls = sorted({p["tl"] for p in plans} - {"wbfs"})
+    if tls:
+        why.append(f"queries with TL {tls} (it simulates TL-WBFS only)")
+    if scn.get("drops_enabled"):
+        why.append("drops_enabled: true (it simulates drops off)")
+    if scn.get("avoid_drop_positives"):
+        why.append("avoid_drop_positives: true (it simulates no drop path)")
+    if scn.get("batching", "dynamic") != "dynamic":
+        why.append(f"batching {scn['batching']!r} (it simulates dynamic batching, "
+                   f"a batch of one with drops off)")
+    unknown = sorted(set(scn) - KNOWN)
+    if unknown:
+        why.append(f"scenario keys it does not simulate: {unknown}")
+    return "refsim refuses " + "; ".join(why) if why else None
+
+
+def books(config: Dict[str, Any], plans: Sequence[Dict], cuts: Sequence[float], *,
+          time32: bool = False) -> Dict[float, Dict[str, Any]]:
+    """The books of one replay at each simulated time of ``cuts`` (the
+    horizon for a finished replay)."""
+    reason = refuses(config, plans)
+    if reason is not None:
+        raise ValueError(reason)
+    sim = Reference(config, World(config["scenario"]), plans, time32=time32)
+    return {t: sim.run_until(t).observe() for t in sorted(set(cuts))}

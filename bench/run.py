@@ -23,11 +23,15 @@ tracks in real time.  With ``--trace 1`` the window is traced and the
 per-layer metrics are read from the trace and the counters
 (``bench/metrics/<name>.py``).
 
-After the window, the plain simulator of the deployment
-(``bench/refsim.py``) and the float64 re-ID reference decide ``correct``
-with the comparisons of ``bench/check.py``: every replay of the window,
-the last one as far as the window took it.  The run fails, printing no
-result, where JAX finds no TPU or fewer chips than the cell asks for.
+After the window, the deployment's plain reference and the float64 re-ID
+reference decide ``correct`` with the comparisons of ``bench/check.py``:
+every replay of the window, the last one as far as the window took it.  A
+configuration names its reference with ``"reference": "<name>"``, the
+module ``bench/<name>.py`` (``bench/refsim.py`` where it names none), which
+offers ``refuses(config, plans)`` and ``books(config, plans, cuts, *,
+time32=False)``.  The run fails, printing no result, where the reference
+refuses the cell (before anything is warmed up), or where JAX finds no TPU
+or fewer chips than the cell asks for.
 """
 
 from __future__ import annotations
@@ -41,11 +45,12 @@ import contextlib  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 from typing import Any, Callable, Dict, List, Optional  # noqa: E402
 
-from . import check, refsim, workload  # noqa: E402
+from . import check, workload  # noqa: E402
 from .workload import BENCH_DIR, ROOT  # noqa: E402
 
 CACHE_DIR = os.path.join(BENCH_DIR, ".cache")
@@ -56,6 +61,10 @@ CACHE_HIT = "/jax/compilation_cache/cache_hits"
 
 class NoChip(RuntimeError):
     pass
+
+
+class Refused(RuntimeError):
+    """The configuration's reference does not simulate the cell."""
 
 
 # --------------------------------------------------------------------- #
@@ -125,12 +134,29 @@ class ReidTap:
         self._held = keep
 
 
-def load_metric(name: str):
-    path = os.path.join(BENCH_DIR, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(f"bench.metrics.{name}", path)
+def _load(modname: str, path: str):
+    spec = importlib.util.spec_from_file_location(modname, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_metric(name: str):
+    return _load(f"bench.metrics.{name}", os.path.join(BENCH_DIR, "metrics", name + ".py")).read
+
+
+REFERENCE_NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}")
+
+
+def load_reference(config: Dict[str, Any]):
+    """The reference module that ``config`` names (``refsim`` where it
+    names none), loaded from ``bench/<name>.py``."""
+    name = config.get("reference", "refsim")
+    path = os.path.join(BENCH_DIR, f"{name}.py")
+    if not REFERENCE_NAME.fullmatch(name) or not os.path.isfile(path):
+        raise FileNotFoundError(f"configuration {config.get('name')!r} names the reference "
+                                f"{name!r}, but bench/{name}.py does not exist")
+    return _load(f"bench.{name}", path)
 
 
 def cell_metrics(bench: Dict, cell: str, kind: str) -> List[Dict]:
@@ -199,12 +225,18 @@ def warm_up(cell: workload.Cell, cfg, specs) -> None:
         replay(cell, cfg, specs, contextlib.nullcontext)
 
 
-def reference_books(cell: workload.Cell, override: Dict[str, Any], plans, cuts):
-    """The plain simulator's books at each simulated time of ``cuts``
-    (ascending; the horizon for a finished replay)."""
-    config = dict(cell.config, scenario=dict(cell.config["scenario"], **override))
-    sim = refsim.Reference(config, refsim.World(config["scenario"]), plans)
-    return {t: sim.run_until(t).observe() for t in sorted(set(cuts))}
+def cell_config(cell: workload.Cell, override: Dict[str, Any]) -> Dict[str, Any]:
+    """The cell's configuration with ``override`` on its scenario keys."""
+    return dict(cell.config, scenario=dict(cell.config["scenario"], **override))
+
+
+def reference_books(cell: workload.Cell, override: Dict[str, Any], plans, cuts, *,
+                    time32: bool = False):
+    """The configuration's reference books at each simulated time of
+    ``cuts`` (the horizon for a finished replay); ``time32`` asks for its
+    float32-time control."""
+    config = cell_config(cell, override)
+    return load_reference(config).books(config, plans, cuts, time32=time32)
 
 
 # --------------------------------------------------------------------- #
@@ -236,6 +268,12 @@ def run_cell(
     sys.path.insert(0, os.path.join(ROOT, "src"))
     bench = workload.load_json(os.path.join(ROOT, "BENCHMARK.json"))
     cell = workload.load_cell(name)
+    plans = workload.query_plans(cell, seed)
+    config = cell_config(cell, override or {})
+    reference = load_reference(config)
+    reason = reference.refuses(config, plans)
+    if reason is not None:
+        raise Refused(f"cell {name}: {reason}")
 
     import jax
     import numpy as np
@@ -260,7 +298,6 @@ def run_cell(
     from repro.sim import WorldKey, get_world
 
     cfg = workload.scenario_config(cell, **(override or {}))
-    plans = workload.query_plans(cell, seed)
     specs = workload.query_specs(plans)
     get_world(WorldKey.from_config(cfg))
     annotate = jax.profiler.TraceAnnotation if trace else contextlib.nullcontext
@@ -324,7 +361,7 @@ def run_cell(
     t_ref = time.perf_counter()
     horizon = cfg.duration_s + 3.0 * cfg.gamma
     cut = [horizon if res is not None else t for res, _, t in results]
-    want = reference_books(cell, override or {}, plans, cut)
+    want = reference.books(config, plans, cut)
     log(f"reference: simulated in {time.perf_counter() - t_ref:.3f} s")
     bad = set()
     first = None
@@ -400,7 +437,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     try:
         out = run_cell(args.workload, args.seed, args.seconds, bool(args.trace))
-    except NoChip as e:
+    except (NoChip, Refused) as e:
         print(f"bench: {e}", file=sys.stderr)
         return 2
     for k, c in out["checks"].items():

@@ -9,6 +9,7 @@ import re
 import pytest
 
 from bench import kernels, run, workload
+from bench.tests import SIZES
 
 BENCH = workload.load_json(os.path.join(workload.ROOT, "BENCHMARK.json"))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -28,6 +29,21 @@ def test_every_name_finds_its_file():
         assert callable(run.load_metric(m["name"]))
         for w in m["workloads"]:
             assert w in {x["name"] for x in BENCH["workloads"]}
+
+
+def test_every_configuration_finds_its_reference():
+    for c in BENCH["configs"]:
+        ref = run.load_reference(workload.load_json(os.path.join(workload.ROOT, c["file"])))
+        assert os.path.dirname(os.path.abspath(ref.__file__)) == workload.BENCH_DIR
+        assert callable(ref.books) and callable(ref.refuses)
+
+
+def test_every_cell_has_its_cpu_size():
+    for w in BENCH["workloads"]:
+        size = workload.load_json(os.path.join(SIZES, w["name"] + ".json"))
+        cell = workload.load_cell(w["name"])
+        assert size and set(size) <= set(cell.config["scenario"])
+        assert workload.scenario_config(cell, **size)
 
 
 def test_every_cell_reports_setup_another_metric_and_a_layer():

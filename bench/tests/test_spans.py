@@ -10,6 +10,7 @@ import os
 import pytest
 
 from bench import run, spans
+from bench.tests import cpu_size
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data", "small.xplane.pb")
 NEW = ("reid_dispatch_us", "reid_launch_us", "reid_wait_us", "tl_tick_us",
@@ -87,7 +88,7 @@ def test_the_trace_is_where_the_harness_writes_it():
 
 def test_traced_cpu_run_reports_every_span_metric():
     out = run.run_cell("paper1000-reid.steady", 2**31 + 11, 1.0, True, require_tpu=False,
-                       override=dict(num_cameras=120, duration_s=40.0),
+                       override=cpu_size("paper1000-reid.steady"),
                        log=lambda _s: None)
     assert out["correct"], out["checks"]
     for name in NEW:
