@@ -148,8 +148,9 @@ class TaskBudget:
         rec = self.get_record(sig.event_id)
         if rec is None:
             return None
-        if sig.q_bar <= 0.0:
-            # No queuing upstream => nothing attributable to this task.
+        if sig.q_bar <= 0.0 or not sig.epsilon:
+            # No queuing upstream, or no excess => nothing attributable to
+            # this task (and no 0 * inf from a denormal ``q_bar``).
             lam = 0.0
         else:
             lam = min(
@@ -171,8 +172,8 @@ class TaskBudget:
         rec = self.get_record(sig.event_id)
         if rec is None:
             return None
-        if sig.xi_bar <= 0.0:
-            share = 0.0
+        if sig.xi_bar <= 0.0 or not sig.epsilon:
+            share = 0.0  # no margin to share (and no 0 * inf)
         else:
             share = sig.epsilon * (rec.xi / sig.xi_bar)
         m = max(rec.batch_size, 1)

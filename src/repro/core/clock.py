@@ -30,6 +30,10 @@ SPANS = (
     "repro.reid.prep",  # validation, padding, device-resident query lookup
     "repro.reid.call",  # the jitted matcher's launch, host operands' transfer included
     "repro.reid.slice",  # the padded answer read to the host and cut to (N, Q)
+    "repro.drop.exec",  # Task._maybe_run: drop point 2 over a formed batch (drops on)
+    "repro.batch.timer",  # Task._timer_fire: the dynamic batcher's auto-submit
+    "repro.budget.reject",  # Task._on_drop: reject signals upstream, and the probe
+    "repro.budget.accept",  # SinkTask._send_accept: accept signals upstream
 )
 #: Prefix of a module instance's user-logic span: ``task.module or task.name``.
 MODULE_SPAN = "repro.module."

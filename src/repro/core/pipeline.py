@@ -356,11 +356,12 @@ class Task:
         self.sim.schedule(delay, self._timer_fire)
 
     def _timer_fire(self) -> None:
-        self._timer_pending = False
-        batch = self.batcher.flush_if_due(self.clock.now(self.sim.time))
-        if batch:
-            self._enqueue_batch(batch)
-        self._arm_timer()
+        with span("repro.batch.timer"):
+            self._timer_pending = False
+            batch = self.batcher.flush_if_due(self.clock.now(self.sim.time))
+            if batch:
+                self._enqueue_batch(batch)
+            self._arm_timer()
 
     # ------------------------------------------------------------------ #
     # Execution: drop point 2, run, drop point 3                         #
@@ -379,27 +380,10 @@ class Task:
             batch = rq.popleft()
             now_local = self.sim.time + self.clock.skew
             if self.drops_enabled:
-                b = len(batch)
-                xi_b = self.xi(b)
-                beta = self.budget.min_budget()
-                tuples = [
-                    (pe.event.header.source_arrival, pe.arrival, now_local - pe.arrival, pe.event)
-                    for pe in batch
-                ]
-                retained_evs, dropped_evs = drop_before_exec(tuples, xi_b, beta)
-                if dropped_evs:
-                    pe_by_id = {pe.event.header.event_id: pe for pe in batch}
-                    for ev in dropped_evs:
-                        self.stats.dropped_dp2 += 1
-                        pe = pe_by_id[ev.header.event_id]
-                        u = pe.arrival - ev.header.source_arrival
-                        q = now_local - pe.arrival
-                        self._on_drop(ev, epsilon=u + q + xi_b - beta, point=2)
-                    if not retained_evs:
-                        continue
-                    retained_pes = [pe_by_id[ev.header.event_id] for ev in retained_evs]
-                else:
-                    retained_pes = batch
+                with span("repro.drop.exec"):
+                    retained_pes = self._drop_before_exec(batch, now_local)
+                if not retained_pes:
+                    continue
             else:
                 retained_pes = batch
             exec_dur = self.xi(len(retained_pes))
@@ -408,6 +392,28 @@ class Task:
             self._busy = True
             self.sim.schedule(exec_dur, self._finish_and_continue, retained_pes, now_local, exec_dur)
             return
+
+    def _drop_before_exec(
+        self, batch: List[PendingEvent], now_local: float
+    ) -> List[PendingEvent]:
+        """Drop point 2 over a formed batch; returns the events retained."""
+        xi_b = self.xi(len(batch))
+        beta = self.budget.min_budget()
+        tuples = [
+            (pe.event.header.source_arrival, pe.arrival, now_local - pe.arrival, pe.event)
+            for pe in batch
+        ]
+        retained_evs, dropped_evs = drop_before_exec(tuples, xi_b, beta)
+        if not dropped_evs:
+            return batch
+        pe_by_id = {pe.event.header.event_id: pe for pe in batch}
+        for ev in dropped_evs:
+            self.stats.dropped_dp2 += 1
+            pe = pe_by_id[ev.header.event_id]
+            u = pe.arrival - ev.header.source_arrival
+            q = now_local - pe.arrival
+            self._on_drop(ev, epsilon=u + q + xi_b - beta, point=2)
+        return [pe_by_id[ev.header.event_id] for ev in retained_evs]
 
     def _finish_and_continue(
         self, batch: List[PendingEvent], exec_start: float, exec_dur: float
@@ -774,34 +780,35 @@ class Task:
         if self.tracer is not None:
             # Drop causality as a span event (the span ends here).
             self.tracer.on_drop(self, header, self.sim.time, point, epsilon)
-        sig = RejectSignal(
-            event_id=header.event_id,
-            epsilon=max(epsilon, 0.0),
-            q_bar=header.q_bar,
-            from_task=self.name,
-        )
-        for up in self._path_tasks(header.path):
-            up.receive_reject(sig)
-        # Probe every k-th dropped event: re-inject it as un-droppable so it
-        # traverses the NORMAL path (including this task's own executor) —
-        # each task along the way then has an event record for the accept
-        # signal to act on, which is what lets a collapsed budget recover
-        # (§4.5.2).
-        if self.probe_every > 0 and self._drop_count % self.probe_every == 0:
-            self.stats.probes += 1
-            probe = Event(
-                header=EventHeader(
-                    event_id=header.event_id,
-                    source_arrival=header.source_arrival,
-                    xi_bar=header.xi_bar,
-                    q_bar=header.q_bar,
-                    is_probe=True,
-                    path=header.path,
-                ),
-                key=ev.key,
-                value=ev.value,
+        with span("repro.budget.reject"):
+            sig = RejectSignal(
+                event_id=header.event_id,
+                epsilon=max(epsilon, 0.0),
+                q_bar=header.q_bar,
+                from_task=self.name,
             )
-            self.sim.schedule(0.0, self.on_arrival, probe)
+            for up in self._path_tasks(header.path):
+                up.receive_reject(sig)
+            # Probe every k-th dropped event: re-inject it as un-droppable so
+            # it traverses the NORMAL path (including this task's own
+            # executor) — each task along the way then has an event record
+            # for the accept signal to act on, which is what lets a collapsed
+            # budget recover (§4.5.2).
+            if self.probe_every > 0 and self._drop_count % self.probe_every == 0:
+                self.stats.probes += 1
+                probe = Event(
+                    header=EventHeader(
+                        event_id=header.event_id,
+                        source_arrival=header.source_arrival,
+                        xi_bar=header.xi_bar,
+                        q_bar=header.q_bar,
+                        is_probe=True,
+                        path=header.path,
+                    ),
+                    key=ev.key,
+                    value=ev.value,
+                )
+                self.sim.schedule(0.0, self.on_arrival, probe)
         # The event dies here; its header can be recycled (see events.py).
         ev.header = None  # type: ignore[assignment]
         release_header(header)
@@ -899,11 +906,12 @@ class SinkTask(Task):
             release_header(header)
 
     def _send_accept(self, ev: Event, epsilon: float) -> None:
-        sig = AcceptSignal(
-            event_id=ev.header.event_id,
-            epsilon=epsilon,
-            xi_bar=ev.header.xi_bar,
-            from_task=self.name,
-        )
-        for up in self._path_tasks(ev.header.path):
-            up.receive_accept(sig)
+        with span("repro.budget.accept"):
+            sig = AcceptSignal(
+                event_id=ev.header.event_id,
+                epsilon=epsilon,
+                xi_bar=ev.header.xi_bar,
+                from_task=self.name,
+            )
+            for up in self._path_tasks(ev.header.path):
+                up.receive_accept(sig)

@@ -21,7 +21,7 @@ import pytest
 
 hypothesis = pytest.importorskip("hypothesis")
 
-from hypothesis import given, settings, strategies as st
+from hypothesis import example, given, settings, strategies as st
 
 from repro.core.budget import TaskBudget
 from repro.core.events import AcceptSignal, EventRecord, RejectSignal
@@ -57,12 +57,20 @@ accepts = st.builds(
     xi_bar=st.floats(0.0, 10.0),
 )
 
+# Shrunk cases: a zero excess over a denormal average (0 * inf) must leave
+# the share at 0, not NaN.
+DENORMAL = 5e-324
+QUEUED = EventRecord(departure=1.0, queuing=1.0, batch_size=1, xi=1.0)
+REJECT_0_DENORMAL = RejectSignal(0, 0.0, DENORMAL)
+ACCEPT_0_DENORMAL = AcceptSignal(0, 0.0, DENORMAL)
+
 
 # --------------------------------------------------------------------- #
 # Monotonicity                                                           #
 # --------------------------------------------------------------------- #
 @settings(max_examples=200, deadline=None)
 @given(rec=records, first=rejects, later=st.lists(rejects, min_size=1, max_size=6))
+@example(rec=QUEUED, first=REJECT_0_DENORMAL, later=[RejectSignal(0, 1.0, 1.0)])
 def test_reject_never_raises_budget(rec, first, later):
     tb = make_budget()
     tb.record(0, rec)
@@ -77,6 +85,7 @@ def test_reject_never_raises_budget(rec, first, later):
 
 @settings(max_examples=200, deadline=None)
 @given(rec=records, first=accepts, later=st.lists(accepts, min_size=1, max_size=6))
+@example(rec=QUEUED, first=ACCEPT_0_DENORMAL, later=[AcceptSignal(0, 1.0, 1.0)])
 def test_accept_never_lowers_budget(rec, first, later):
     tb = make_budget()
     tb.record(0, rec)
@@ -97,6 +106,7 @@ def test_accept_never_lowers_budget(rec, first, later):
     pairs=st.lists(st.tuples(records, rejects), min_size=2, max_size=6),
     seed=st.integers(0, 2**16),
 )
+@example(pairs=[(QUEUED, REJECT_0_DENORMAL), (QUEUED, RejectSignal(0, 1.0, 1.0))], seed=1)
 def test_out_of_order_rejects_converge(pairs, seed):
     """Any delivery order of the same reject set yields the same budget."""
     import random
@@ -121,6 +131,7 @@ def test_out_of_order_rejects_converge(pairs, seed):
     pairs=st.lists(st.tuples(records, accepts), min_size=2, max_size=6),
     seed=st.integers(0, 2**16),
 )
+@example(pairs=[(QUEUED, ACCEPT_0_DENORMAL), (QUEUED, AcceptSignal(0, 1.0, 1.0))], seed=1)
 def test_out_of_order_accepts_converge(pairs, seed):
     import random
 

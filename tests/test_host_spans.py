@@ -27,25 +27,26 @@ def _parent(iv, spans, pred):
     return min(held, key=lambda s: s[1] - s[0]) if held else None
 
 
-@pytest.fixture(scope="module")
-def traced(tmp_path_factory):
-    """A small re-ID deployment stepped one frame period at a time under a
-    CPU profiler trace: its ``repro.*`` host spans and the dispatch count."""
+#: The budget and drop plane's spans: they fire only with drops on.
+DROP_PLANE = ("repro.drop.exec", "repro.batch.timer", "repro.budget.reject",
+              "repro.budget.accept")
+
+
+def _trace(cfg, out, ticks):
+    """Two queries over ``cfg`` stepped one frame period at a time under a
+    CPU profiler trace: the ``repro.*`` host spans and the dispatch count."""
     import jax
     from jax.profiler import ProfileData
 
-    cfg = ScenarioConfig(num_cameras=200, duration_s=12.0, seed=0, tl="wbfs",
-                         batching="dynamic", m_max=25, embed_dim=16)
     scn = MultiQueryScenario(cfg, [QuerySpec(), QuerySpec(embedding_seed=99)])
     scn.run_until(1.0)  # compile outside the trace
-    out = str(tmp_path_factory.mktemp("trace"))
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     opts.host_tracer_level = 1
     dispatch.reset_stats()
     jax.profiler.start_trace(out, profiler_options=opts)
     try:
-        for k in range(2, 13):
+        for k in range(2, ticks + 1):
             scn.run_until(float(k))
     finally:
         jax.profiler.stop_trace()
@@ -58,6 +59,25 @@ def traced(tmp_path_factory):
     return spans, calls
 
 
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """A small re-ID deployment with drops off."""
+    cfg = ScenarioConfig(num_cameras=200, duration_s=12.0, seed=0, tl="wbfs",
+                         batching="dynamic", m_max=25, embed_dim=16)
+    return _trace(cfg, str(tmp_path_factory.mktemp("trace")), 12)
+
+
+@pytest.fixture(scope="module")
+def traced_drops(tmp_path_factory):
+    """A small overloaded deployment with drops on (one VA, one CR, TL-BFS
+    at 7 m/s): VA drops at dp2, batches auto-submit, accepts come back."""
+    cfg = ScenarioConfig(num_cameras=200, duration_s=20.0, seed=0, tl="bfs",
+                         tl_peak_speed=7.0, batching="dynamic", m_max=25,
+                         drops_enabled=True, avoid_drop_positives=True, num_va=1,
+                         num_cr=1, embed_dim=16)
+    return _trace(cfg, str(tmp_path_factory.mktemp("trace_drops")), 20)
+
+
 def test_every_span_name_is_in_the_closed_list(traced):
     spans, _ = traced
     names = {n for _, _, n in spans}
@@ -65,6 +85,16 @@ def test_every_span_name_is_in_the_closed_list(traced):
     assert all(n in SPANS or n.startswith(MODULE_SPAN) for n in names), names
     assert all(n.startswith("repro.") for n in SPANS)
     assert {MODULE_SPAN + m for m in ("VA", "CR", "UV")} <= names
+
+
+def test_drop_plane_spans_fire_with_drops_on(traced_drops):
+    names = {n for _, _, n in traced_drops[0]}
+    assert set(DROP_PLANE) <= names and set(DROP_PLANE) <= set(SPANS)
+    assert all(n in SPANS or n.startswith(MODULE_SPAN) for n in names), names
+
+
+def test_drop_plane_spans_stay_silent_with_drops_off(traced):
+    assert not set(DROP_PLANE) & {n for _, _, n in traced[0]}
 
 
 def test_each_step_is_one_des_run_span(traced):
