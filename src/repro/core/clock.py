@@ -21,15 +21,16 @@ __all__ = ["Clock", "monotonic", "span", "SPANS", "MODULE_SPAN"]
 
 #: Every host span the platform emits, besides ``MODULE_SPAN + <module>``.
 SPANS = (
-    "repro.des.run",  # TrackingScenario.run_until: the event loop to t
-    "repro.des.drain",  # TrackingScenario.run: the event loop to the horizon
+    "repro.des.run",  # TrackingScenario.run_until: the event loop to t, VA's late re-ID reads
+    "repro.des.drain",  # TrackingScenario.run: the event loop to the horizon, the same reads
     "repro.tl.tick",  # one TL tick: spotlights and control deltas
     "repro.va.reid_build",  # VA re-ID: gallery stack and tenancy mask
-    "repro.va.reid_wait",  # VA re-ID: reading the answer, which the dispatch returns on the host
-    "repro.reid.dispatch",  # dispatch.reid_match_multi, whole
-    "repro.reid.prep",  # validation, padding, device-resident query lookup
-    "repro.reid.call",  # the jitted matcher's launch, host operands' transfer included
-    "repro.reid.slice",  # the padded answer read to the host and cut to (N, Q)
+    "repro.va.reid_wait",  # VA re-ID: reading one answer, the queue's flush when it is the first
+    "repro.reid.dispatch",  # dispatch.reid_match_multi, whole: the call queued
+    "repro.reid.prep",  # validation, copies, device-resident query lookup
+    "repro.reid.flush",  # dispatch.reid_flush: every queued call answered
+    "repro.reid.call",  # one launch of the jitted matcher, host operands' transfer included
+    "repro.reid.slice",  # the launch's answer read to the host and cut per call
     "repro.drop.exec",  # Task._maybe_run: drop point 2 over a formed batch (drops on)
     "repro.batch.timer",  # Task._timer_fire: the dynamic batcher's auto-submit
     "repro.budget.reject",  # Task._on_drop: reject signals upstream, and the probe

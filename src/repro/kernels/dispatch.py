@@ -18,9 +18,11 @@ This layer makes kernel launches sweep-friendly:
   network and re-id query blocks are uploaded once and cached by operand
   identity (weakly referenced, so a dropped world frees its buffers).
   Per-call padded scratch operands of the single-query and spotlight
-  kernels are donated to the kernel.  ``reid_match_multi`` makes one device
-  launch a call: its scratch operands go into the jitted call as host
-  arrays, and its padded answer comes back to the host and is cut there.
+  kernels are donated to the kernel.
+* **queued re-id** — ``reid_match_multi`` queues its call and returns
+  handles; the first read of a queued answer answers the whole queue with
+  one device launch per query block (scratch operands as host arrays), and
+  cuts each call's answer out on the host.
 * **cache-miss accounting** — :func:`stats` counts calls and distinct
   bucket shapes, and :func:`jit_cache_sizes` exposes the underlying jit
   caches so tests can assert "at most one compile per bucket shape".
@@ -45,10 +47,14 @@ from repro.core.clock import span
 __all__ = [
     "BUCKET_MIN",
     "MAX_JIT_SHAPES",
+    "REID_MULTI_ROWS",
     "bucket",
     "spotlight_ball",
     "reid_match",
     "reid_match_multi",
+    "reid_flush",
+    "reid_queued",
+    "ReidAnswer",
     "stats",
     "reset_stats",
     "profile",
@@ -71,6 +77,7 @@ MAX_JIT_SHAPES = 32
 _STATS = {
     "reid_calls": 0,
     "reid_multi_calls": 0,
+    "reid_multi_launches": 0,
     "ball_calls": 0,
     "device_cache_hits": 0,
     "device_cache_misses": 0,
@@ -422,29 +429,75 @@ def reid_match(gallery, queries, *, threshold: float = 0.5):
 # --------------------------------------------------------------------- #
 # Query-major batched re-id (multi-query tenancy plane)                   #
 # --------------------------------------------------------------------- #
+#: Gallery rows of the multi matcher's smallest launch, and the rows one
+#: query block may queue: a flush answers up to this many queued rows in one
+#: launch of one program per query bucket.  A call of more rows launches
+#: alone, in ``bucket(N)`` rows.
+REID_MULTI_ROWS = 64
+
+
 def _make_reid_multi_padded():
     import jax
     import jax.numpy as jnp
 
     @jax.jit
-    def reid_multi_padded(gallery, queries, mask, threshold):
+    def reid_multi_padded(gallery, queries, mask):
         # Per-(candidate, query) cosine similarity with a broadcast
         # multiply-then-reduce over the feature axis: every sim[n, q] is an
         # independent D-length reduction whose arithmetic does not depend on
         # how many other rows/queries share the bucket — which is what makes
-        # the fused call bit-exact against per-query serial dispatches.
+        # the fused call bit-exact against per-query serial dispatches, and
+        # a flush's stacked rows bit-exact against one launch a call.
         g = gallery.astype(jnp.float32)
         q = queries.astype(jnp.float32)
         g = g / jnp.maximum(jnp.linalg.norm(g, axis=-1, keepdims=True), 1e-6)
         q = q / jnp.maximum(jnp.linalg.norm(q, axis=-1, keepdims=True), 1e-6)
         sim = jnp.sum(g[:, None, :] * q[None, :, :], axis=-1)  # (Nb, Qb)
-        sim = jnp.where(mask, sim, -jnp.inf)
-        return sim, jnp.logical_and(mask, sim >= threshold)
+        return jnp.where(mask, sim, -jnp.inf)
 
     return reid_multi_padded
 
 
 _REID_MULTI_PADDED = None
+
+
+class ReidAnswer:
+    """One half of a :func:`reid_match_multi` answer.  ``np.asarray`` reads
+    it as a read-only host ``(N, Q)`` array; reading an answer whose call
+    is still queued first answers every queued call (:func:`reid_flush`)."""
+
+    __slots__ = ("value",)
+
+    def __init__(self) -> None:
+        self.value: Optional[np.ndarray] = None
+
+    def __array__(self, dtype=None, copy=None):
+        if self.value is None:
+            reid_flush()
+            if self.value is None:
+                raise RuntimeError("re-ID answer lost: the launch that held it failed")
+        v = self.value
+        if dtype is not None and np.dtype(dtype) != v.dtype:
+            return v.astype(dtype)
+        return v.copy() if copy else v
+
+
+class _Block:
+    """The queued calls against one query block, in call order."""
+
+    __slots__ = ("queries", "q_dev", "qb", "rows", "calls")
+
+    def __init__(self, queries, q_dev, qb: int) -> None:
+        self.queries = queries  # held, so its id names this block while queued
+        self.q_dev = q_dev
+        self.qb = qb
+        self.rows = 0
+        #: (gallery, mask, threshold, scores, matched) per call
+        self.calls: list = []
+
+
+# Query-block key -> its queued calls, blocks in the order of their first call.
+_QUEUE: Dict[object, _Block] = {}
 
 
 def reid_match_multi(gallery, queries, *, mask=None, threshold: float = 0.5):
@@ -454,32 +507,34 @@ def reid_match_multi(gallery, queries, *, mask=None, threshold: float = 0.5):
     ``mask`` (optional ``(N, Q)`` bool) is the tenancy filter: pair
     ``(n, q)`` is only evaluated when ``mask[n, q]`` — masked-out pairs get
     ``-inf`` score and ``matched=False``.  Both axes are padded to
-    power-of-two buckets (pad pairs masked out), so a whole multi-query
-    sweep compiles this kernel at most once per bucket shape.
+    power-of-two buckets (pad pairs masked out; rows to at least
+    :data:`REID_MULTI_ROWS`), so a whole multi-query sweep compiles this
+    kernel at most once per bucket shape.
 
-    One device launch per call: the padded gallery, mask and threshold go
-    into the jitted call as host operands, and the padded answer is copied
-    back and cut to ``(N, Q)`` on the host, so the answers are host
-    ``np.ndarray`` views (float32 scores, bool flags), read-only.
+    The call validates its operands and queues them; it launches nothing.
+    The answers are :class:`ReidAnswer` handles: ``np.asarray`` reads each
+    as a read-only host array (float32 scores, bool flags).  The first read
+    of any queued answer answers the whole queue with one device launch per
+    query block, the block's calls' rows stacked in call order, and cuts
+    each call's answer out on the host; ``matched`` is ``mask & (scores >=
+    float32(threshold))`` there.
 
     Bit-exactness contract: each ``sim[n, q]`` is an independent
     normalize-then-reduce over ``D``, so real entries are **bitwise** equal
-    to a per-query serial call (``Q=1``) with the same gallery rows — unlike
-    :func:`reid_match`, no GEMM re-blocking is involved.  The fused
-    multi-query VA stage relies on this to stay bit-identical to N
-    independent single-query runs.
+    to a per-query serial call (``Q=1``) with the same gallery rows, and to
+    the same call launched alone — unlike :func:`reid_match`, no GEMM
+    re-blocking is involved.  The fused multi-query VA stage relies on this
+    to stay bit-identical to N independent single-query runs.
     """
     with span("repro.reid.dispatch"):
-        return _reid_match_multi(gallery, queries, mask, threshold)
+        return _reid_enqueue(gallery, queries, mask, threshold)
 
 
-def _reid_match_multi(gallery, queries, mask, threshold):
-    global _REID_MULTI_PADDED
-    import jax.numpy as jnp
-
+def _reid_enqueue(gallery, queries, mask, threshold):
     with span("repro.reid.prep"):
         _STATS["reid_multi_calls"] += 1
-        gallery = np.asarray(gallery, dtype=np.float32)
+        # Copies: the queue outlives the caller's buffers.
+        gallery = np.array(gallery, dtype=np.float32)
         if gallery.ndim != 2:
             raise ValueError(f"gallery must be (N, D), got {gallery.shape}")
         N, D = gallery.shape
@@ -490,14 +545,10 @@ def _reid_match_multi(gallery, queries, mask, threshold):
         if mask is None:
             mask_np = np.ones((N, Q), dtype=bool)
         else:
-            mask_np = np.asarray(mask, dtype=bool)
+            mask_np = np.array(mask, dtype=bool)
             if mask_np.shape != (N, Q):
                 raise ValueError(f"mask must be ({N}, {Q}), got {mask_np.shape}")
-        nb, qb = bucket(N), bucket(Q)
-        g_pad = np.zeros((nb, D), dtype=np.float32)
-        g_pad[:N] = gallery
-        m_pad = np.zeros((nb, qb), dtype=bool)
-        m_pad[:N, :Q] = mask_np
+        qb = bucket(Q)
 
         def _pad_queries(_q):
             q_pad = np.zeros((qb, D), dtype=np.float32)
@@ -508,23 +559,71 @@ def _reid_match_multi(gallery, queries, mask, threshold):
             # The live-query block is long-lived (the query registry caches
             # one array per live set): pad once, keep device-resident by
             # identity — same contract as the single-query reid_match block.
+            key = id(queries)
             q_dev = _device_resident(queries, transform=_pad_queries)
         else:
-            q_dev = jnp.asarray(_pad_queries(queries_np))
+            key = object()  # a block of its own
+            q_dev = _pad_queries(queries_np)
+        block = _QUEUE.get(key)
+    if block is not None and block.rows + N > REID_MULTI_ROWS:
+        reid_flush()
+        block = None
+    if block is None:
+        block = _QUEUE[key] = _Block(queries, q_dev, qb)
+    scores, matched = ReidAnswer(), ReidAnswer()
+    block.calls.append((gallery, mask_np, np.float32(threshold), scores, matched))
+    block.rows += N
+    return scores, matched
 
-        if _REID_MULTI_PADDED is None:
-            _REID_MULTI_PADDED = _make_reid_multi_padded()
-        key = ("reid_multi", nb, qb, D)
-        _note_shape(key)
-        bound_jit_cache("reid_multi", _REID_MULTI_PADDED, key)
+
+def reid_queued() -> int:
+    """Calls of :func:`reid_match_multi` not yet answered."""
+    return sum(len(b.calls) for b in _QUEUE.values())
+
+
+def reid_flush() -> None:
+    """Answer every queued :func:`reid_match_multi` call: one launch of the
+    matcher per query block, the block's rows stacked in call order."""
+    if not _QUEUE:
+        return
+    blocks = list(_QUEUE.values())
+    _QUEUE.clear()
+    with span("repro.reid.flush"):
+        for block in blocks:
+            _reid_launch(block)
+
+
+def _reid_launch(block: _Block) -> None:
+    global _REID_MULTI_PADDED
+
+    D = block.calls[0][0].shape[1]
+    nb = bucket(block.rows, REID_MULTI_ROWS)
+    g_pad = np.zeros((nb, D), dtype=np.float32)
+    m_pad = np.zeros((nb, block.qb), dtype=bool)
+    r = 0
+    for g, m, _, _, _ in block.calls:
+        g_pad[r:r + len(g)] = g
+        m_pad[r:r + len(g), :m.shape[1]] = m
+        r += len(g)
+    if _REID_MULTI_PADDED is None:
+        _REID_MULTI_PADDED = _make_reid_multi_padded()
+    key = ("reid_multi", nb, block.qb, D)
+    _note_shape(key)
+    bound_jit_cache("reid_multi", _REID_MULTI_PADDED, key)
+    _STATS["reid_multi_launches"] += 1
     with span("repro.reid.call"):
-        # A host f32 scalar keeps the threshold's abstract value f32[].
-        thr = np.float32(threshold)
-        scores, matched = _REID_MULTI_PADDED(g_pad, q_dev, m_pad, thr)
+        sim = _REID_MULTI_PADDED(g_pad, block.q_dev, m_pad)
     with span("repro.reid.slice"):
-        scores.copy_to_host_async()
-        matched.copy_to_host_async()
-        return np.asarray(scores)[:N, :Q], np.asarray(matched)[:N, :Q]
+        sim = np.asarray(sim)
+        sim.flags.writeable = False
+        r = 0
+        for g, m, thr, scores, matched in block.calls:
+            s = sim[r:r + len(g), :m.shape[1]]
+            # The kernel's own float32 comparison, made on the host.
+            flags = m & (s >= thr)
+            flags.flags.writeable = False
+            scores.value, matched.value = s, flags
+            r += len(g)
 
 
 def jit_cache_sizes() -> Dict[str, int]:

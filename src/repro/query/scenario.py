@@ -16,7 +16,10 @@ singular:
   are charged **per query**.
 * **Fused analytics** — with embeddings enabled, each VA batch runs ONE
   query-major ``reid_match_multi`` dispatch over all live query embeddings
-  (per-pair tenancy mask), instead of one ``reid_match`` per query.  With
+  (per-pair tenancy mask), instead of one ``reid_match`` per query.  Where
+  only counters read the answers (no ``avoid_drop_positives``), they are
+  read at the end of the DES step, so the dispatch plane answers all of a
+  step's dispatches with one device launch per query block.  With
   ``spotlight_mode="kernel"`` the blind-spot queries' balls are computed by
   ONE multi-source ``spotlight_ball`` invocation
   (:func:`repro.core.tracking.multi_source_spotlight` — the same
@@ -198,6 +201,9 @@ class MultiQueryScenario(TrackingScenario):
         self._mask_of = {}
         self._source_hook = self._on_sourced
         self._pending_masks: List[int] = []
+        #: Re-ID answers VA has not read yet, ``(block_states, matched)``
+        #: in call order; applied by ``_settle_reid``.
+        self._reid_pending: List[Tuple[List[QueryState], Any]] = []
         self.compiled.install_drop_hook(self._on_pipeline_drop)
 
         t_q = time.perf_counter()
@@ -586,10 +592,24 @@ class MultiQueryScenario(TrackingScenario):
         _, matched = dispatch.reid_match_multi(
             gallery, block, mask=mask, threshold=self.cfg.reid_threshold
         )
+        if not self.deployment.avoid_drop_positives:
+            # Only counters read this answer: it is read with the rest of
+            # the step's, which the dispatch plane answers in one launch.
+            self._reid_pending.append((block_states, matched))
+            return
+        hits = self._credit_reid(block_states, matched)
+        for row, i in enumerate(idx):
+            if hits[row]:
+                events[i].header.avoid_drop = True
+
+    def _credit_reid(self, block_states: List[QueryState], matched) -> List[bool]:
+        """Read one re-ID answer and count its matches for the queries of
+        its call (``block_states``, as they were at call time); returns,
+        per gallery row, whether any query matched it."""
         with span("repro.va.reid_wait"):
             matched = np.asarray(matched)
-        avoid = self.deployment.avoid_drop_positives
-        for row, i in enumerate(idx):
+        hits = []
+        for row in range(matched.shape[0]):
             hit = False
             for col, st in enumerate(block_states):
                 if matched[row, col]:
@@ -597,8 +617,13 @@ class MultiQueryScenario(TrackingScenario):
                     hit = True
             if hit:
                 self._reid_matched += 1
-                if avoid:
-                    events[i].header.avoid_drop = True
+            hits.append(hit)
+        return hits
+
+    def _settle_reid(self) -> None:  # overrides TrackingScenario
+        pending, self._reid_pending = self._reid_pending, []
+        for block_states, matched in pending:
+            self._credit_reid(block_states, matched)
 
     # ------------------------------------------------------------------ #
     # Telemetry + quality: per-query keyed rows                           #
@@ -656,6 +681,7 @@ class MultiQueryScenario(TrackingScenario):
         query's registry ledger, and the admission queue.  Bit-comparable
         between a replayed and an uninterrupted run (and npz-persistable via
         :mod:`repro.training.checkpoint`)."""
+        self._settle_reid()
         snap: Dict[str, float] = {
             "time": float(self.sim.time),
             "source_events": float(self._source_events),

@@ -590,6 +590,10 @@ class TrackingScenario:
                 if avoid:
                     events[i].header.avoid_drop = True
 
+    def _settle_reid(self) -> None:
+        """Apply the re-ID answers VA left to read at the end of a step.
+        The single-query VA hook reads each answer at once: none is left."""
+
     # ------------------------------------------------------------------ #
     # Sink + TL feedback                                                  #
     # ------------------------------------------------------------------ #
@@ -815,6 +819,7 @@ class TrackingScenario:
         self._schedule_ticks()
         with span("repro.des.run"):
             self.sim.run(until=min(t, self._horizon))
+            self._settle_reid()
 
     # ------------------------------------------------------------------ #
     def run(self) -> ScenarioResult:
@@ -823,6 +828,7 @@ class TrackingScenario:
         # Allow in-flight events to drain past the generation horizon.
         with span("repro.des.drain"):
             self.sim.run(until=self._horizon)
+            self._settle_reid()
 
         if self._trace is not None:
             # Final sample after the drain: cumulative counters (drops,

@@ -185,10 +185,12 @@ def test_reid_multi_buckets_and_compile_accounting():
     rng = np.random.default_rng(9)
     D = 40  # private to this test, like the single-query compile test
     before = dispatch.stats()["reid_multi_calls"]
-    dispatch.reid_match_multi(rng.normal(size=(2, D)).astype(np.float32),
-                              rng.normal(size=(1, D)).astype(np.float32))
+    np.asarray(dispatch.reid_match_multi(rng.normal(size=(2, D)).astype(np.float32),
+                                         rng.normal(size=(1, D)).astype(np.float32))[0])
     base = dispatch.jit_cache_sizes()["reid_multi"]
-    # Gallery 1..8 and queries 1..8 share one (8, 8, D) bucket shape.
+    # Gallery 1..64 and queries 1..8 share one (64, 8, D) bucket shape: the
+    # row axis never launches below REID_MULTI_ROWS.
+    assert dispatch.REID_MULTI_ROWS == 64
     for N, Q in ((1, 1), (3, 2), (8, 8), (5, 7)):
         g = rng.normal(size=(N, D)).astype(np.float32)
         q = rng.normal(size=(Q, D)).astype(np.float32)
@@ -197,29 +199,31 @@ def test_reid_multi_buckets_and_compile_accounting():
         assert np.asarray(matched).shape == (N, Q)
     assert dispatch.jit_cache_sizes()["reid_multi"] == base
     # A new bucket (Q > 8) costs exactly one more compile.
-    dispatch.reid_match_multi(rng.normal(size=(2, D)).astype(np.float32),
-                              rng.normal(size=(9, D)).astype(np.float32))
+    np.asarray(dispatch.reid_match_multi(rng.normal(size=(2, D)).astype(np.float32),
+                                         rng.normal(size=(9, D)).astype(np.float32))[0])
     assert dispatch.jit_cache_sizes()["reid_multi"] == base + 1
     assert dispatch.stats()["reid_multi_calls"] == before + 6
 
 
 def test_reid_multi_one_program_per_call_and_host_answers():
     """Once a bucket is warm, a call with any other (N, Q) pair in it
-    compiles nothing: the matcher is the one program a dispatch runs, and
-    the answer is cut to (N, Q) on the host.  The answers are host arrays
-    bit-equal to the padded kernel's output cut there."""
+    compiles nothing: the matcher is the one program a launch runs, and
+    each call read on its own is one launch.  The answers are handles that
+    read to host arrays bit-equal to the padded kernel's output cut there,
+    the flags its float32 comparison with the threshold."""
     import jax
     import jax.numpy as jnp
 
     rng = np.random.default_rng(14)
     D, thr = 44, 0.05  # D private to this test: its bucket starts cold
-    pairs = ((1, 9), (2, 16), (7, 9), (8, 13), (5, 12))  # one (8, 16) bucket
+    pairs = ((1, 9), (2, 16), (7, 9), (8, 13), (5, 12))  # one (64, 16) bucket
     calls = []
     for N, Q in pairs:
         calls.append((rng.normal(size=(N, D)).astype(np.float32),
                       rng.normal(size=(Q, D)).astype(np.float32),
                       rng.random((N, Q)) < 0.7))
-    dispatch.reid_match_multi(*calls[0][:2], mask=calls[0][2], threshold=thr)
+    np.asarray(dispatch.reid_match_multi(*calls[0][:2], mask=calls[0][2],
+                                         threshold=thr)[1])
 
     compiles = []
 
@@ -227,29 +231,33 @@ def test_reid_multi_one_program_per_call_and_host_answers():
         if event == "/jax/core/compile/backend_compile_duration":
             compiles.append(secs)
 
+    launches = dispatch.stats()["reid_multi_launches"]
     jax.monitoring.register_event_duration_secs_listener(on_duration)
     try:
-        answers = [dispatch.reid_match_multi(g, q, mask=m, threshold=thr)
-                   for g, q, m in calls[1:]]
+        answers = []
+        for g, q, m in calls[1:]:
+            scores, matched = dispatch.reid_match_multi(g, q, mask=m, threshold=thr)
+            answers.append((np.asarray(scores), np.asarray(matched)))
     finally:
         jax.monitoring.unregister_event_duration_listener(on_duration)
     assert compiles == []
+    assert dispatch.stats()["reid_multi_launches"] == launches + len(calls) - 1
 
     for (g, q, m), (scores, matched) in zip(calls[1:], answers):
         N, Q = m.shape
         assert type(scores) is np.ndarray and type(matched) is np.ndarray
         assert scores.dtype == np.float32 and matched.dtype == np.bool_
         assert scores.shape == matched.shape == (N, Q)
-        g_pad = np.zeros((8, D), np.float32)
+        g_pad = np.zeros((64, D), np.float32)
         g_pad[:N] = g
         q_pad = np.zeros((16, D), np.float32)
         q_pad[:Q] = q
-        m_pad = np.zeros((8, 16), bool)
+        m_pad = np.zeros((64, 16), bool)
         m_pad[:N, :Q] = m
-        want_s, want_m = dispatch._REID_MULTI_PADDED(
-            jnp.asarray(g_pad), jnp.asarray(q_pad), jnp.asarray(m_pad), jnp.float32(thr))
-        np.testing.assert_array_equal(scores, np.asarray(want_s)[:N, :Q])
-        np.testing.assert_array_equal(matched, np.asarray(want_m)[:N, :Q])
+        want_s = np.asarray(dispatch._REID_MULTI_PADDED(
+            jnp.asarray(g_pad), jnp.asarray(q_pad), jnp.asarray(m_pad)))[:N, :Q]
+        np.testing.assert_array_equal(scores, want_s)
+        np.testing.assert_array_equal(matched, m & (want_s >= np.float32(thr)))
     flags = np.concatenate([m.ravel() for _, m in answers])
     assert flags.any() and not flags.all()
 
@@ -271,3 +279,117 @@ def test_jit_cache_is_bounded(monkeypatch):
     dispatch.reid_match(rng.normal(size=(2, 76)).astype(np.float32),
                         rng.normal(size=(1, 76)).astype(np.float32))
     assert dispatch.jit_cache_sizes()["reid"] == size
+
+
+# --------------------------------------------------------------------- #
+# The re-ID queue: calls answered by one launch per query block          #
+# --------------------------------------------------------------------- #
+def _reid_calls(rng, block, sizes, D):
+    """One ``(gallery, mask)`` call against ``block`` per row count."""
+    return [(rng.normal(size=(n, D)).astype(np.float32),
+             rng.random((n, block.shape[0])) < 0.8) for n in sizes]
+
+
+def _launches():
+    return dispatch.stats()["reid_multi_launches"]
+
+
+def test_reid_queue_one_launch_for_a_block_and_answers_as_one_at_a_time():
+    rng = np.random.default_rng(31)
+    D, thr = 48, 0.1
+    block = rng.normal(size=(5, D)).astype(np.float32)
+    calls = _reid_calls(rng, block, (1, 3, 1, 7, 2), D)
+    alone = []
+    for g, m in calls:
+        s, f = dispatch.reid_match_multi(g, block, mask=m, threshold=thr)
+        alone.append((np.asarray(s), np.asarray(f)))
+    before = _launches()
+    queued = [dispatch.reid_match_multi(g, block, mask=m, threshold=thr) for g, m in calls]
+    assert dispatch.reid_queued() == len(calls) and _launches() == before
+    np.asarray(queued[2][1])
+    assert dispatch.reid_queued() == 0 and _launches() == before + 1
+    for (s, f), (want_s, want_f) in zip(queued, alone):
+        np.testing.assert_array_equal(np.asarray(s), want_s)
+        np.testing.assert_array_equal(np.asarray(f), want_f)
+    assert _launches() == before + 1
+
+
+def test_reid_queue_launches_once_per_query_block():
+    rng = np.random.default_rng(32)
+    D = 48
+    a = rng.normal(size=(3, D)).astype(np.float32)
+    b = rng.normal(size=(9, D)).astype(np.float32)
+    dispatch.reid_flush()
+    before = _launches()
+    answers = [dispatch.reid_match_multi(g, blk, mask=m)
+               for blk in (a, b, a, b)
+               for g, m in _reid_calls(rng, blk, (2,), D)]
+    assert dispatch.reid_queued() == 4
+    np.asarray(answers[-1][0])
+    assert _launches() == before + 2 and dispatch.reid_queued() == 0
+    assert [np.asarray(s).shape for s, _ in answers] == [(2, 3), (2, 9), (2, 3), (2, 9)]
+
+
+def test_reid_queue_row_bound_forces_a_flush():
+    rng = np.random.default_rng(33)
+    D = 48
+    block = rng.normal(size=(4, D)).astype(np.float32)
+    rows = dispatch.REID_MULTI_ROWS
+    (g1, m1), (g2, m2), (g3, m3) = _reid_calls(rng, block, (rows - 10, 11, rows + 5), D)
+    dispatch.reid_flush()
+    before = _launches()
+    first = dispatch.reid_match_multi(g1, block, mask=m1)
+    assert dispatch.reid_queued() == 1 and _launches() == before
+    # 11 more rows would overflow the block's launch: the queue goes first.
+    second = dispatch.reid_match_multi(g2, block, mask=m2)
+    assert dispatch.reid_queued() == 1 and _launches() == before + 1
+    # A call of more rows than the bound flushes the queue and launches alone.
+    third = dispatch.reid_match_multi(g3, block, mask=m3)
+    assert dispatch.reid_queued() == 1 and _launches() == before + 2
+    assert np.asarray(third[0]).shape == (rows + 5, 4)
+    assert _launches() == before + 3
+    assert np.asarray(first[0]).shape == (rows - 10, 4)
+    assert np.asarray(second[0]).shape == (11, 4)
+    assert _launches() == before + 3
+
+
+def test_reid_answer_handles_offer_no_async_copy():
+    rng = np.random.default_rng(34)
+    g = rng.normal(size=(2, 48)).astype(np.float32)
+    q = rng.normal(size=(3, 48)).astype(np.float32)
+    for answer in dispatch.reid_match_multi(g, q):
+        assert isinstance(answer, dispatch.ReidAnswer)
+        assert not hasattr(answer, "copy_to_host_async")
+    dispatch.reid_flush()
+
+
+def test_reid_answers_read_to_read_only_host_arrays():
+    rng = np.random.default_rng(35)
+    g = rng.normal(size=(6, 48)).astype(np.float32)
+    q = rng.normal(size=(3, 48)).astype(np.float32)
+    scores, matched = dispatch.reid_match_multi(g, q, threshold=0.0)
+    for answer, dtype in ((scores, np.float32), (matched, np.bool_)):
+        host = np.asarray(answer)
+        assert type(host) is np.ndarray and host.dtype == dtype and host.shape == (6, 3)
+        assert not host.flags.writeable
+        with pytest.raises(ValueError):
+            host[0, 0] = host[0, 1]
+        copy = np.array(answer)
+        assert copy.flags.writeable and np.array_equal(copy, host)
+        assert np.asarray(answer, dtype=np.float64).dtype == np.float64
+
+
+def test_reid_flags_never_leave_their_mask():
+    rng = np.random.default_rng(36)
+    D = 48
+    block = rng.normal(size=(7, D)).astype(np.float32)
+    # Gallery rows close to the queries, so most evaluated pairs match.
+    answers = []
+    for _ in range(6):
+        g = (block[rng.integers(0, 7, 5)] + 0.05 * rng.normal(size=(5, D))).astype(np.float32)
+        m = rng.random((5, 7)) < 0.5
+        answers.append((m, dispatch.reid_match_multi(g, block, mask=m, threshold=-0.5)))
+    for m, (scores, matched) in answers:
+        s, f = np.asarray(scores), np.asarray(matched)
+        assert f[m].any() and not f[~m].any()
+        assert np.all(np.isneginf(s[~m])) and np.all(np.isfinite(s[m]))

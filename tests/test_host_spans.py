@@ -105,20 +105,39 @@ def test_each_step_is_one_des_run_span(traced):
 
 
 def test_dispatch_sits_in_module_logic_in_a_des_step(traced):
+    """A dispatch queues its call inside VA's logic; the step's reads come
+    at its end, still inside its ``repro.des.run``, where the first one
+    flushes the queue: a launch and its answer's read per query block."""
     spans, calls = traced
     disp = [s for s in spans if s[2] == "repro.reid.dispatch"]
     assert calls > 0 and len(disp) == calls
+    in_step = lambda s: _parent(s, spans, lambda n: n == "repro.des.run") is not None
     for d in disp:
         module = _parent(d, spans, lambda n: n.startswith(MODULE_SPAN))
         assert module is not None and module[2] == MODULE_SPAN + "VA"
-        assert _parent(module, spans, lambda n: n == "repro.des.run") is not None
+        assert in_step(module)
         parts = [s[2] for s in spans if s[2].startswith("repro.reid.")
                  and s is not d and _inside(s, d)]
-        assert parts == ["repro.reid.prep", "repro.reid.call", "repro.reid.slice"]
-    for name in ("repro.va.reid_build", "repro.va.reid_wait"):
-        mine = [s for s in spans if s[2] == name]
-        assert len(mine) == calls
-        assert all(_parent(s, spans, lambda n: n == MODULE_SPAN + "VA") for s in mine)
+        assert parts == ["repro.reid.prep"]
+    flushes = [s for s in spans if s[2] == "repro.reid.flush"]
+    steps = sum(n == "repro.des.run" for _, _, n in spans)
+    assert 0 < len(flushes) <= min(calls, steps)
+    launches = 0
+    for f in flushes:
+        wait = _parent(f, spans, lambda n: n == "repro.va.reid_wait")
+        assert wait is not None and in_step(wait)
+        parts = sorted((s[0], s[2]) for s in spans if s[2].startswith("repro.reid.")
+                       and s is not f and _inside(s, f))
+        names = [n for _, n in parts]
+        assert names and names == ["repro.reid.call", "repro.reid.slice"] * (len(names) // 2)
+        launches += len(names) // 2
+    assert launches == len([s for s in spans if s[2] == "repro.reid.call"])
+    builds = [s for s in spans if s[2] == "repro.va.reid_build"]
+    waits = [s for s in spans if s[2] == "repro.va.reid_wait"]
+    assert len(builds) == len(waits) == calls
+    assert all(_parent(s, spans, lambda n: n == MODULE_SPAN + "VA") for s in builds)
+    assert all(in_step(s) and not _parent(s, spans, lambda n: n.startswith(MODULE_SPAN))
+               for s in waits)
 
 
 def test_span_leaves_jax_unimported():

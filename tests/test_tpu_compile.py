@@ -199,15 +199,15 @@ def test_reid_match_kernel_compiles_for_v5e(one_chip):
 
 
 def test_reid_match_multi_compiles_for_v5e(one_chip):
-    """The padded query-major matcher every VA batch of a multi-query run
-    dispatches: a 32-row gallery bucket (VA batches hold at most m_max=25
-    frames) against 16 queries at embed_dim=128."""
+    """The padded query-major matcher a multi-query run's VA batches are
+    answered by: the 64-row gallery bucket a flush launches (VA batches hold
+    at most m_max=25 frames) against 16 queries at embed_dim=128."""
     from repro.kernels import dispatch
 
+    rows = dispatch.REID_MULTI_ROWS
     compiled = dispatch._make_reid_multi_padded().lower(
-        _sds(one_chip, (32, 128), jnp.float32),
+        _sds(one_chip, (rows, 128), jnp.float32),
         _sds(one_chip, (16, 128), jnp.float32),
-        _sds(one_chip, (32, 16), jnp.bool_),
-        _sds(one_chip, (), jnp.float32),
+        _sds(one_chip, (rows, 16), jnp.bool_),
     ).compile()
     _fits_one_chip(compiled)
