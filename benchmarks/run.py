@@ -989,7 +989,7 @@ def bench_kernels(ctx=None) -> None:
 
     from repro.kernels.decode_attention.ops import decode_attention
     from repro.kernels.flash_attention.ops import flash_attention
-    from repro.kernels.reid_match.ops import reid_match
+    from repro.kernels.dispatch import reid_match_multi
     from repro.kernels.spotlight_ball.ops import spotlight_ball
     from repro.kernels.ssd_scan.ops import ssd_scan
 
@@ -1030,7 +1030,7 @@ def bench_kernels(ctx=None) -> None:
 
     g = jax.random.normal(key, (4096, 128))
     qq = jax.random.normal(key, (4, 128))
-    timeit("reid_match_4k", lambda *a: reid_match(*a)[0], g, qq,
+    timeit("reid_match_4k", lambda *a: np.asarray(reid_match_multi(*a)[0]), g, qq,
            derived="gallery=4096x128")
 
     from repro.core.roadnet import make_road_network

@@ -9,7 +9,7 @@ worlds built from ``--seed`` and the on-disk world cache off:
   A  re-ID analytics: ``MultiQueryScenario`` with ``embed_dim=128`` (the
      App 4 width); every VA batch goes through
      ``dispatch.reid_match_multi`` on the chip.  Per-query books must equal
-     a run whose matcher is the host reference (``kernels/reid_match/ref``
+     a run whose matcher is the host reference (``kernels/reid_ref``
      on the CPU backend).
   B  spotlight kernel: ``TLProbabilistic.spotlight_multi(use_kernel=True)``
      (the Pallas min-plus relaxation) on the 1000-camera road graph must
@@ -54,7 +54,7 @@ import numpy as np  # noqa: E402
 from repro.core.tracking import TLProbabilistic  # noqa: E402
 from repro.kernels import dispatch  # noqa: E402
 from repro.kernels.megastep import ops as megastep_ops  # noqa: E402
-from repro.kernels.reid_match.ref import reid_match_ref  # noqa: E402
+from repro.kernels.reid_ref import reid_match_ref  # noqa: E402
 from repro.kernels.spotlight_ball.ref import (  # noqa: E402
     dense_adjacency,
     spotlight_ball_ref,

@@ -177,13 +177,13 @@ def build_app4(road, cameras, batching="dynamic", entity_embedding=None):
 
     def reid_small(frames, q):  # VA: cheap tower filters candidates
         embs = np.asarray(embed_frames(small_tower, jnp.asarray(_features(frames))))
-        _, _, hits = dispatch.reid_match(embs, _query(q, "small"), threshold=0.3)
-        return [[(0, 0, 64, 128)] if bool(h) else [] for h in np.asarray(hits)]
+        _, hits = dispatch.reid_match_multi(embs, _query(q, "small"), threshold=0.3)
+        return [[(0, 0, 64, 128)] if bool(h) else [] for h in np.asarray(hits)[:, 0]]
 
     def reid_large(crops, q):  # CR: accurate tower confirms
         embs = np.asarray(embed_frames(large_tower, jnp.asarray(_features(crops))))
-        _, _, hits = dispatch.reid_match(embs, _query(q, "large"), threshold=0.7)
-        return [bool(h) for h in np.asarray(hits)]
+        _, hits = dispatch.reid_match_multi(embs, _query(q, "large"), threshold=0.7)
+        return [bool(h) for h in np.asarray(hits)[:, 0]]
 
     return TrackingApp(
         name="app4",

@@ -4,8 +4,9 @@
     PYTHONPATH=src python examples/track_person.py [--cameras 500] [--duration 240]
 
 * VA/CR are actual jit-compiled JAX models (re-id embedding tower + the
-  ``reid_match`` kernel) executed through :class:`ServedStage` — Anveshak's
-  budgeted dynamic batching + drop points wrap every device call.
+  dispatch plane's ``reid_match_multi`` matcher) executed through
+  :class:`ServedStage` — Anveshak's budgeted dynamic batching + drop points
+  wrap every device call.
 * The stage cost models ``xi(b)`` are *calibrated from the compiled step*
   (replacing the paper's offline benchmarking) and then drive the
   discrete-event scenario at full scale.
@@ -24,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.serving import ServedStage, StageRequest, calibrate_xi, embed_frames, init_reid_tower
-from repro.kernels.reid_match.ops import reid_match
+from repro.kernels import dispatch
 from repro.sim import ScenarioConfig, TrackingScenario
 
 
@@ -63,12 +64,12 @@ def main() -> None:
         for r in results or []:
             if r.dropped:
                 continue
-            score, _, hit = reid_match(r.output[None, :], jnp.asarray(query_emb), threshold=0.7)
-            matches += int(hit[0])
+            _, hit = dispatch.reid_match_multi(r.output[None, :], query_emb, threshold=0.7)
+            matches += int(np.asarray(hit)[0, 0])
     for r in stage.flush() or []:
         if not r.dropped:
-            score, _, hit = reid_match(r.output[None, :], jnp.asarray(query_emb), threshold=0.7)
-            matches += int(hit[0])
+            _, hit = dispatch.reid_match_multi(r.output[None, :], query_emb, threshold=0.7)
+            matches += int(np.asarray(hit)[0, 0])
     wall = time.perf_counter() - t0
     print(
         f"Served {n_requests} frames in {wall:.2f}s "

@@ -1,24 +1,23 @@
 """Bucket-batched kernel dispatch (the sweep engine's analytics plane).
 
-``reid_match`` and ``spotlight_ball`` are called with whatever batch size
-the simulation happens to produce — a fresh jit specialization per (Q, N)
-pair means a sweep of scenarios recompiles the same kernels over and over.
-This layer makes kernel launches sweep-friendly:
+``reid_match_multi`` and ``spotlight_ball`` are called with whatever batch
+size the simulation happens to produce — a fresh jit specialization per
+(Q, N) pair means a sweep of scenarios recompiles the same kernels over and
+over.  This layer makes kernel launches sweep-friendly:
 
 * **bucketing** — batch dimensions are padded up to power-of-two buckets
   (minimum :data:`BUCKET_MIN`), so an entire sweep compiles each kernel at
   most once per bucket shape.  Padding is masked out: spotlight pad rows
   get radius ``-1`` -> all-``inf`` and the min-plus relaxation is
   row-independent, so spotlight results are **bitwise** equal to the
-  unpadded call; re-id pad queries are masked to ``-inf`` similarity, but
-  padding the gallery changes the GEMM blocking, so re-id scores agree
-  with the unpadded call only up to last-ulp reassociation (still fully
-  deterministic for a given shape).
+  unpadded call; re-id pad pairs are masked to ``-inf`` similarity, and
+  each similarity is its own reduction over the feature axis, so a real
+  pair's answer does not depend on the padding.
 * **device-resident operands** — the dense min-plus adjacency of a road
   network and re-id query blocks are uploaded once and cached by operand
   identity (weakly referenced, so a dropped world frees its buffers).
-  Per-call padded scratch operands of the single-query and spotlight
-  kernels are donated to the kernel.
+  Per-call padded scratch operands of the spotlight kernel are donated to
+  it.
 * **queued re-id** — ``reid_match_multi`` queues its call and returns
   handles; the first read of a queued answer answers the whole queue with
   one device launch per query block (scratch operands as host arrays), and
@@ -50,7 +49,6 @@ __all__ = [
     "REID_MULTI_ROWS",
     "bucket",
     "spotlight_ball",
-    "reid_match",
     "reid_match_multi",
     "reid_flush",
     "reid_queued",
@@ -75,7 +73,6 @@ BUCKET_MIN = 8
 MAX_JIT_SHAPES = 32
 
 _STATS = {
-    "reid_calls": 0,
     "reid_multi_calls": 0,
     "reid_multi_launches": 0,
     "ball_calls": 0,
@@ -340,93 +337,6 @@ def spotlight_ball(indptr, indices, weights, sources, radii, *, dtype=np.float32
 
 
 # --------------------------------------------------------------------- #
-# Batched re-id matching                                                 #
-# --------------------------------------------------------------------- #
-def _make_reid_padded():
-    import jax
-    import jax.numpy as jnp
-
-    donate = (0,) if jax.default_backend() == "tpu" else ()
-
-    # threshold is traced (not static): sweeps vary it per config, and a
-    # static arg would recompile per distinct value — violating the
-    # one-compile-per-bucket-shape contract without showing up in stats.
-    @functools.partial(jax.jit, donate_argnums=donate)
-    def reid_padded(gallery, queries, nq, threshold):
-        # Same arithmetic as reid_match_ref, with pad queries masked to
-        # -inf similarity so they can never win the per-candidate max.
-        g = gallery.astype(jnp.float32)
-        q = queries.astype(jnp.float32)
-        g = g / jnp.maximum(jnp.linalg.norm(g, axis=-1, keepdims=True), 1e-6)
-        q = q / jnp.maximum(jnp.linalg.norm(q, axis=-1, keepdims=True), 1e-6)
-        # HIGHEST: a TPU runs a default-precision f32 matmul as bf16 passes.
-        sim = jnp.matmul(g, q.T, precision=jax.lax.Precision.HIGHEST)  # (N, Qb)
-        valid = jnp.arange(q.shape[0])[None, :] < nq
-        sim = jnp.where(valid, sim, -jnp.inf)
-        scores = jnp.max(sim, axis=-1)
-        best = jnp.argmax(sim, axis=-1).astype(jnp.int32)
-        return scores, best, scores >= threshold
-
-    return reid_padded
-
-
-_REID_PADDED = None
-
-
-def reid_match(gallery, queries, *, threshold: float = 0.5):
-    """Bucket-padded re-id matcher: ``(scores, best_query, is_match)`` for
-    the first ``N`` gallery rows, matching the unpadded
-    ``repro.kernels.reid_match`` call up to last-ulp GEMM reassociation
-    (padding changes the matmul blocking; results are deterministic per
-    shape).
-
-    The gallery (per-call candidate embeddings) is padded to a
-    power-of-two row bucket and donated; the query block (often a
-    long-lived entity embedding) is padded once and kept device-resident
-    keyed on its identity.
-    """
-    global _REID_PADDED
-    import jax.numpy as jnp
-
-    _STATS["reid_calls"] += 1
-    gallery = np.asarray(gallery, dtype=np.float32)
-    if gallery.ndim != 2:
-        raise ValueError(f"gallery must be (N, D), got {gallery.shape}")
-    N, D = gallery.shape
-    nb = bucket(N)
-    g_pad = np.zeros((nb, D), dtype=np.float32)
-    g_pad[:N] = gallery
-
-    queries_np = np.asarray(queries, dtype=np.float32)
-    if queries_np.ndim != 2 or queries_np.shape[1] != D:
-        raise ValueError(f"queries must be (Q, {D}), got {queries_np.shape}")
-    Q = queries_np.shape[0]
-    qb = bucket(Q)
-
-    def _pad_queries(_q):
-        q_pad = np.zeros((qb, D), dtype=np.float32)
-        q_pad[:Q] = queries_np
-        return q_pad
-
-    if isinstance(queries, np.ndarray):
-        # Long-lived query blocks (the tracked entity's embedding) stay
-        # device-resident, padded once, keyed on the host array identity.
-        q_dev = _device_resident(queries, transform=_pad_queries)
-    else:
-        q_dev = jnp.asarray(_pad_queries(queries_np))
-
-    if _REID_PADDED is None:
-        _REID_PADDED = _make_reid_padded()
-    key = ("reid", nb, qb, D)
-    _note_shape(key)
-    bound_jit_cache("reid", _REID_PADDED, key)
-    scores, best, matched = _REID_PADDED(
-        jnp.asarray(g_pad), q_dev, jnp.int32(Q), jnp.float32(threshold)
-    )
-    return scores[:N], best[:N], matched[:N]
-
-
-# --------------------------------------------------------------------- #
 # Query-major batched re-id (multi-query tenancy plane)                   #
 # --------------------------------------------------------------------- #
 #: Gallery rows of the multi matcher's smallest launch, and the rows one
@@ -522,9 +432,9 @@ def reid_match_multi(gallery, queries, *, mask=None, threshold: float = 0.5):
     Bit-exactness contract: each ``sim[n, q]`` is an independent
     normalize-then-reduce over ``D``, so real entries are **bitwise** equal
     to a per-query serial call (``Q=1``) with the same gallery rows, and to
-    the same call launched alone — unlike :func:`reid_match`, no GEMM
-    re-blocking is involved.  The fused multi-query VA stage relies on this
-    to stay bit-identical to N independent single-query runs.
+    the same call launched alone: no GEMM re-blocking is involved.  The
+    fused multi-query VA stage relies on this to stay bit-identical to N
+    independent single-query runs, which match through this call too.
     """
     with span("repro.reid.dispatch"):
         return _reid_enqueue(gallery, queries, mask, threshold)
@@ -556,9 +466,9 @@ def _reid_enqueue(gallery, queries, mask, threshold):
             return q_pad
 
         if isinstance(queries, np.ndarray):
-            # The live-query block is long-lived (the query registry caches
-            # one array per live set): pad once, keep device-resident by
-            # identity — same contract as the single-query reid_match block.
+            # A query block is long-lived (the query registry caches one
+            # array per live set; a single query is the entity's embedding):
+            # pad once, keep device-resident by identity.
             key = id(queries)
             q_dev = _device_resident(queries, transform=_pad_queries)
         else:
@@ -636,7 +546,6 @@ def jit_cache_sizes() -> Dict[str, int]:
         name: 0 if fn is None else fn._cache_size()
         for name, fn in (
             ("ball", _BALL_PADDED),
-            ("reid", _REID_PADDED),
             ("reid_multi", _REID_MULTI_PADDED),
             ("megastep", _mega_ops._CHUNK_FN),
         )

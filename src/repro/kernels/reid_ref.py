@@ -2,8 +2,9 @@
 
 Given a gallery of candidate embeddings (detections cropped from frames) and
 one or more query embeddings (the entity, possibly fused by QF), compute
-L2-normalized cosine similarities and per-candidate best-query scores.  The
-Pallas kernel tiles the gallery over VMEM; this is its ground truth.
+L2-normalized cosine similarities and per-candidate best-query scores.  It
+is the plain reference that ``repro.kernels.dispatch.reid_match_multi`` is
+checked against.
 """
 
 from __future__ import annotations

@@ -2,8 +2,8 @@
 
 ``spotlight_ball(indptr, indices, weights, sources, radii)`` relaxes a batch
 of Q query balls over the CSR road graph and returns (Q, V) distances with
-``inf`` outside each query's radius.  Backend selection mirrors
-``reid_match``: the dense min-plus fixpoint runs through the Pallas kernel on
+``inf`` outside each query's radius.  Backend selection mirrors the other
+kernel packages: the dense min-plus fixpoint runs through the Pallas kernel on
 TPU (or when forced via ``REPRO_FORCE_PALLAS=1``, interpreted off-TPU) and
 through the pure-jnp reference otherwise.
 """

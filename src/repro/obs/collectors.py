@@ -371,7 +371,7 @@ def collect_dispatch(registry: MetricsRegistry) -> MetricsRegistry:
         labels=("kernel",),
         domain=WALL,
     )
-    for kind in ("reid_calls", "reid_multi_calls", "ball_calls"):
+    for kind in ("reid_multi_calls", "ball_calls"):
         if stats.get(kind):
             calls.inc(stats[kind], kernel=kind.rsplit("_calls", 1)[0])
     cache = registry.counter(

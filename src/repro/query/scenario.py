@@ -16,10 +16,10 @@ singular:
   are charged **per query**.
 * **Fused analytics** — with embeddings enabled, each VA batch runs ONE
   query-major ``reid_match_multi`` dispatch over all live query embeddings
-  (per-pair tenancy mask), instead of one ``reid_match`` per query.  Where
-  only counters read the answers (no ``avoid_drop_positives``), they are
-  read at the end of the DES step, so the dispatch plane answers all of a
-  step's dispatches with one device launch per query block.  With
+  (per-pair tenancy mask), instead of one dispatch per query.  The VA hook
+  and the rule for when it reads an answer are the single-query driver's
+  (``TrackingScenario._va_reid``); this driver supplies the live block, the
+  tenancy mask and the per-query credit.  With
   ``spotlight_mode="kernel"`` the blind-spot queries' balls are computed by
   ONE multi-source ``spotlight_ball`` invocation
   (:func:`repro.core.tracking.multi_source_spotlight` — the same
@@ -201,9 +201,6 @@ class MultiQueryScenario(TrackingScenario):
         self._mask_of = {}
         self._source_hook = self._on_sourced
         self._pending_masks: List[int] = []
-        #: Re-ID answers VA has not read yet, ``(block_states, matched)``
-        #: in call order; applied by ``_settle_reid``.
-        self._reid_pending: List[Tuple[List[QueryState], Any]] = []
         self.compiled.install_drop_hook(self._on_pipeline_drop)
 
         t_q = time.perf_counter()
@@ -568,62 +565,32 @@ class MultiQueryScenario(TrackingScenario):
                 st.orphan_dropped += 1
 
     # ------------------------------------------------------------------ #
-    # Fused cross-query re-ID (overrides the single-query VA batch hook)  #
+    # Fused cross-query re-ID: the tenancy and credit of the VA re-ID hook #
     # ------------------------------------------------------------------ #
-    def _va_reid(self, events: List[Event], state: Dict) -> None:
-        from repro.kernels import dispatch
-
-        with span("repro.va.reid_build"):
-            block, block_states = self.registry.embedding_block()
-            if not block_states:
-                return
-            embs = [getattr(ev.value, "embedding", None) for ev in events]
-            idx = [i for i, e in enumerate(embs) if e is not None]
-            if not idx:
-                return
-            gallery = np.stack([embs[i] for i in idx])
-            nq = len(block_states)
-            mask = np.zeros((len(idx), nq), dtype=bool)
-            for row, i in enumerate(idx):
-                m = events[i].query_mask
-                for col, st in enumerate(block_states):
-                    if m & st.bit:
-                        mask[row, col] = True
-        _, matched = dispatch.reid_match_multi(
-            gallery, block, mask=mask, threshold=self.cfg.reid_threshold
-        )
-        if not self.deployment.avoid_drop_positives:
-            # Only counters read this answer: it is read with the rest of
-            # the step's, which the dispatch plane answers in one launch.
-            self._reid_pending.append((block_states, matched))
-            return
-        hits = self._credit_reid(block_states, matched)
+    def _reid_tenancy(self, events: List[Event], idx: List[int]):  # overrides TrackingScenario
+        """The live-query block, its states in bit order, and each gallery
+        row's tenancy mask (pair ``(row, q)`` is evaluated only when the
+        row's frame was sourced for query ``q``)."""
+        block, block_states = self.registry.embedding_block()
+        if not block_states:
+            return None
+        mask = np.zeros((len(idx), len(block_states)), dtype=bool)
         for row, i in enumerate(idx):
-            if hits[row]:
-                events[i].header.avoid_drop = True
+            m = events[i].query_mask
+            for col, st in enumerate(block_states):
+                if m & st.bit:
+                    mask[row, col] = True
+        return block, block_states, mask
 
     def _credit_reid(self, block_states: List[QueryState], matched) -> List[bool]:
-        """Read one re-ID answer and count its matches for the queries of
-        its call (``block_states``, as they were at call time); returns,
-        per gallery row, whether any query matched it."""
-        with span("repro.va.reid_wait"):
-            matched = np.asarray(matched)
-        hits = []
-        for row in range(matched.shape[0]):
-            hit = False
-            for col, st in enumerate(block_states):
-                if matched[row, col]:
-                    st.reid_matched += 1
-                    hit = True
-            if hit:
-                self._reid_matched += 1
-            hits.append(hit)
+        """Also count each query's matches, for the queries of the call
+        (``block_states``, as they were at call time)."""
+        hits = super()._credit_reid(block_states, matched)
+        if True in hits:  # a few calls in a hundred
+            per_query = np.count_nonzero(np.asarray(matched), axis=0).tolist()
+            for st, n in zip(block_states, per_query):
+                st.reid_matched += n
         return hits
-
-    def _settle_reid(self) -> None:  # overrides TrackingScenario
-        pending, self._reid_pending = self._reid_pending, []
-        for block_states, matched in pending:
-            self._credit_reid(block_states, matched)
 
     # ------------------------------------------------------------------ #
     # Telemetry + quality: per-query keyed rows                           #

@@ -1,7 +1,8 @@
 """Re-identification tower: the CR stage's embedding model.
 
 A compact residual MLP mapping frame feature vectors to L2-normalizable
-embeddings; matching runs through the ``reid_match`` kernel (Pallas on TPU).
+embeddings; matching runs through the dispatch plane's re-ID matcher
+(``repro.kernels.dispatch.reid_match_multi``).
 This is the JAX analogue of the paper's OpenReid DNN in CR (App 1) and the
 small/large re-id pair of App 4.
 """
@@ -13,8 +14,9 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.kernels.reid_match.ops import reid_match
+from repro.kernels import dispatch
 from repro.models.layers import Params, init_linear, init_norm, linear, rms_norm
 
 __all__ = ["init_reid_tower", "embed_frames", "match"]
@@ -49,7 +51,11 @@ def embed_frames(params: Params, frames: jax.Array) -> jax.Array:
     return linear(params["proj_out"], x)
 
 
-def match(params: Params, frames: jax.Array, queries: jax.Array, threshold: float = 0.5):
-    """Full CR stage: embed candidate frames, match against query embeddings."""
+def match(
+    params: Params, frames: jax.Array, queries: jax.Array, threshold: float = 0.5
+) -> Tuple[np.ndarray, np.ndarray]:
+    """Full CR stage: embed candidate frames, match against query embeddings.
+    Returns host ``(scores, matched)`` of shape ``(N, Q)``."""
     gallery = embed_frames(params, frames)
-    return reid_match(gallery, queries, threshold=threshold)
+    scores, matched = dispatch.reid_match_multi(gallery, queries, threshold=threshold)
+    return np.asarray(scores), np.asarray(matched)

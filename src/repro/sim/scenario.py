@@ -512,6 +512,9 @@ class TrackingScenario:
         self._positives_generated = 0
         self._positives_completed = 0
         self._reid_matched = 0
+        #: Re-ID answers VA has not read yet, ``(tenants, matched)`` in call
+        #: order; credited by ``_settle_reid``.
+        self._reid_pending: List[Tuple[Any, Any]] = []
         self._detections_on_time = 0
         self._pending_detections: List[Detection] = []
         self._source_events = 0
@@ -567,32 +570,62 @@ class TrackingScenario:
     # Driver-side instrumentation hooks                                   #
     # ------------------------------------------------------------------ #
     def _va_reid(self, events: List[Event], state: Dict) -> None:
-        """Batched re-ID over the batch's frame embeddings: one bucket-padded
-        ``reid_match`` call per VA batch (gallery = the frames' embeddings,
-        query = the tracked entity's embedding).  Matches count toward
-        ``ScenarioResult.reid_matched`` and — like the ground-truth candidate
-        filter — flag avoid-drop when the config asks for it (§4.3.3)."""
+        """Batched re-ID over the batch's frame embeddings: one
+        ``reid_match_multi`` call per VA batch (gallery = the frames'
+        embeddings, queries = the block :meth:`_reid_tenancy` names).
+        Matches count toward ``reid_matched``.  With ``avoid_drop_positives``
+        (§4.3.3) the answer is read at once and a matched frame is flagged
+        avoid-drop before its next drop check.  Otherwise only counters read
+        it: it is credited with the rest of the step's by
+        :meth:`_settle_reid`, so the dispatch plane answers a step's calls
+        with one launch per query block."""
         from repro.kernels import dispatch
 
-        embs = [getattr(ev.value, "embedding", None) for ev in events]
-        idx = [i for i, e in enumerate(embs) if e is not None]
-        if not idx:
-            return
-        gallery = np.stack([embs[i] for i in idx])
-        _, _, matched = dispatch.reid_match(
-            gallery, self._reid_query, threshold=self.cfg.reid_threshold
+        with span("repro.va.reid_build"):
+            embs = [getattr(ev.value, "embedding", None) for ev in events]
+            idx = [i for i, e in enumerate(embs) if e is not None]
+            if not idx:
+                return
+            tenancy = self._reid_tenancy(events, idx)
+            if tenancy is None:
+                return
+            block, tenants, mask = tenancy
+            gallery = np.stack([embs[i] for i in idx])
+        _, matched = dispatch.reid_match_multi(
+            gallery, block, mask=mask, threshold=self.cfg.reid_threshold
         )
-        matched = np.asarray(matched)
-        avoid = self.deployment.avoid_drop_positives
-        for j, i in enumerate(idx):
-            if matched[j]:
-                self._reid_matched += 1
-                if avoid:
-                    events[i].header.avoid_drop = True
+        if not self.deployment.avoid_drop_positives:
+            self._reid_pending.append((tenants, matched))
+            return
+        hits = self._credit_reid(tenants, matched)
+        for row, i in enumerate(idx):
+            if hits[row]:
+                events[i].header.avoid_drop = True
+
+    def _reid_tenancy(
+        self, events: List[Event], idx: List[int]
+    ) -> Optional[Tuple[np.ndarray, Any, Optional[np.ndarray]]]:
+        """``(queries, tenants, mask)`` for the VA batch's gallery rows
+        ``events[i] for i in idx``, or None when no query is live: the
+        single query is the tracked entity's embedding, a long-lived block
+        the dispatch plane keeps on the device, and every row is its."""
+        return self._reid_query, None, None
+
+    def _credit_reid(self, tenants: Any, matched) -> List[bool]:
+        """Read one re-ID answer and count the gallery rows some query
+        matched; returns, per row, whether one did.  ``tenants`` is what
+        :meth:`_reid_tenancy` returned with the call's block."""
+        with span("repro.va.reid_wait"):
+            matched = np.asarray(matched)
+        hits = [True in row for row in matched.tolist()]
+        self._reid_matched += sum(hits)
+        return hits
 
     def _settle_reid(self) -> None:
-        """Apply the re-ID answers VA left to read at the end of a step.
-        The single-query VA hook reads each answer at once: none is left."""
+        """Credit the re-ID answers VA left to read at the end of a step."""
+        pending, self._reid_pending = self._reid_pending, []
+        for tenants, matched in pending:
+            self._credit_reid(tenants, matched)
 
     # ------------------------------------------------------------------ #
     # Sink + TL feedback                                                  #

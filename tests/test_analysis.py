@@ -253,7 +253,7 @@ def test_jax003_fires_only_in_the_megastep_plane():
     assert rules_of(JAX003_POS, path="kernels/megastep/ops.py") == ["JAX003"]
     assert rules_of(JAX003_POS, path="core/megastep.py") == ["JAX003"]
     # Other kernels own their dtype (f32 embeddings are the contract there).
-    assert rules_of(JAX003_POS, path="kernels/reid_match/ops.py") == []
+    assert rules_of(JAX003_POS, path="kernels/spotlight_ball/ops.py") == []
 
 
 def test_jax003_quiet_on_f64():

@@ -9,7 +9,7 @@ pytest.importorskip("jax")
 
 from repro.core.roadnet import make_road_network
 from repro.kernels import dispatch
-from repro.kernels.reid_match.ref import reid_match_ref
+from repro.kernels.reid_ref import reid_match_ref
 from repro.kernels.spotlight_ball.ops import spotlight_ball as ops_spotlight_ball
 
 
@@ -44,27 +44,28 @@ def test_spotlight_ball_padding_is_invisible(road):
 
 
 def test_reid_match_padding_matches_up_to_ulp():
-    # Padding the gallery changes the GEMM blocking, so scores may differ
-    # from the unpadded call in the last ulp (deterministically per shape);
-    # matches must agree everywhere the score isn't within an ulp of the
-    # threshold.
+    # The matcher reduces each pair on its own while the reference runs a
+    # GEMM, so the best score per gallery row may differ from the
+    # reference's in the last ulp; matches must agree everywhere the score
+    # isn't within an ulp of the threshold.
     rng = np.random.default_rng(3)
     threshold = 0.3
     for D in (16, 32):
         queries = rng.normal(size=(3, D)).astype(np.float32)
         for N in (1, 2, 8, 11, 40):
             gallery = rng.normal(size=(N, D)).astype(np.float32)
-            got_s, got_b, got_m = [
-                np.asarray(x) for x in dispatch.reid_match(gallery, queries, threshold=threshold)
+            got_s, got_m = [
+                np.asarray(x)
+                for x in dispatch.reid_match_multi(gallery, queries, threshold=threshold)
             ]
-            ref_s, ref_b, ref_m = [
+            ref_s, _, ref_m = [
                 np.asarray(x) for x in reid_match_ref(gallery, queries, threshold=threshold)
             ]
-            assert got_s.shape == ref_s.shape
-            np.testing.assert_allclose(got_s, ref_s, rtol=2e-6, atol=2e-7)
+            assert got_s.shape == got_m.shape == (N, 3)
+            np.testing.assert_allclose(got_s.max(axis=1), ref_s, rtol=2e-6, atol=2e-7)
             clear = np.abs(ref_s - threshold) > 1e-5
-            np.testing.assert_array_equal(got_m[clear], ref_m[clear])
-            # Self-consistency: is_match is exactly scores >= threshold.
+            np.testing.assert_array_equal(got_m.any(axis=1)[clear], ref_m[clear])
+            # Self-consistency: matched is exactly scores >= threshold.
             np.testing.assert_array_equal(
                 got_m, got_s >= np.float32(threshold)
             )
@@ -77,13 +78,13 @@ def test_reid_negative_scores_not_clobbered_by_padding():
         [[1, 1, 0, 0], [1, 2, 0, 0], [2, 1, 0, 0]], dtype=np.float32
     )
     queries = -np.eye(4, dtype=np.float32)[:2]
-    scores, _, matched = [np.asarray(x) for x in dispatch.reid_match(gallery, queries)]
+    scores, matched = [np.asarray(x) for x in dispatch.reid_match_multi(gallery, queries)]
     ref_scores, _, ref_matched = [
         np.asarray(x) for x in reid_match_ref(gallery, queries)
     ]
-    np.testing.assert_allclose(scores, ref_scores, rtol=2e-6, atol=2e-7)
+    np.testing.assert_allclose(scores.max(axis=1), ref_scores, rtol=2e-6, atol=2e-7)
     assert (scores < 0).all() and not matched.any()
-    np.testing.assert_array_equal(matched, ref_matched)
+    np.testing.assert_array_equal(matched.any(axis=1), ref_matched)
 
 
 def test_dense_adjacency_cached_per_network(road):
@@ -109,12 +110,19 @@ def test_at_most_one_compile_per_bucket_shape():
 
     # Warm both kernels once so module-level compilation state exists.
     # D=24 is private to this test: other tests must not pre-compile the
-    # reid shapes whose cache misses are being counted.
+    # reid shapes whose cache misses are being counted.  Each re-id answer
+    # is read, so its call launches.
     D = 24
+
+    def reid(N):
+        scores, _ = dispatch.reid_match_multi(
+            rng.normal(size=(N, D)).astype(np.float32),
+            rng.normal(size=(1, D)).astype(np.float32))
+        return np.asarray(scores)
+
     dispatch.spotlight_ball(indptr, indices, weights,
                             np.zeros(1, np.int32), np.full(1, 10.0, np.float32))
-    dispatch.reid_match(rng.normal(size=(2, D)).astype(np.float32),
-                        rng.normal(size=(1, D)).astype(np.float32))
+    reid(2)
     base = dispatch.jit_cache_sizes()
 
     # A "sweep" of calls: many batch sizes, only two buckets each (8, 16).
@@ -122,15 +130,15 @@ def test_at_most_one_compile_per_bucket_shape():
         sources = rng.integers(0, net.num_vertices, Q).astype(np.int32)
         radii = rng.uniform(10.0, 500.0, Q).astype(np.float32)
         dispatch.spotlight_ball(indptr, indices, weights, sources, radii)
-    for N in (1, 4, 8, 9, 16, 3, 13):
-        dispatch.reid_match(rng.normal(size=(N, D)).astype(np.float32),
-                            rng.normal(size=(1, D)).astype(np.float32))
+    # Gallery rows 1..64 share the warm 64-row bucket; 65..128 -> 128.
+    for N in (1, 40, 64, 65, 100, 128, 77):
+        reid(N)
 
     sizes = dispatch.jit_cache_sizes()
     # Q in 1..8 -> bucket 8 (already warm), 9..16 -> bucket 16: exactly one
     # new compile per kernel despite 10 (7) distinct batch sizes.
     assert sizes["ball"] - base["ball"] == 1
-    assert sizes["reid"] - base["reid"] == 1
+    assert sizes["reid_multi"] - base["reid_multi"] == 1
 
     # Re-running the same sweep adds no compiles at all.
     for Q in (2, 9, 16, 5):
@@ -180,10 +188,10 @@ def test_scenario_reid_path_counts_matches():
 
 def test_reid_multi_buckets_and_compile_accounting():
     """The query-major kernel obeys the same dispatch contracts as the
-    single-query one: power-of-two bucket padding on BOTH axes, call/shape
+    spotlight one: power-of-two bucket padding on BOTH axes, call/shape
     stats, and at most one jit compile per bucket shape."""
     rng = np.random.default_rng(9)
-    D = 40  # private to this test, like the single-query compile test
+    D = 40  # private to this test, like the other compile tests
     before = dispatch.stats()["reid_multi_calls"]
     np.asarray(dispatch.reid_match_multi(rng.normal(size=(2, D)).astype(np.float32),
                                          rng.normal(size=(1, D)).astype(np.float32))[0])
@@ -268,17 +276,22 @@ def test_jit_cache_is_bounded(monkeypatch):
     overflow and rebuilds it for the working set."""
     monkeypatch.setattr(dispatch, "MAX_JIT_SHAPES", 4)
     rng = np.random.default_rng(6)
+
+    def reid(D):  # read, so the call launches
+        scores, _ = dispatch.reid_match_multi(
+            rng.normal(size=(2, D)).astype(np.float32),
+            rng.normal(size=(1, D)).astype(np.float32))
+        np.asarray(scores)
+
     # Each feature width D is its own bucket shape for the reid kernel.
     for D in (52, 56, 60, 64, 68, 72, 76):
-        dispatch.reid_match(rng.normal(size=(2, D)).astype(np.float32),
-                            rng.normal(size=(1, D)).astype(np.float32))
-        assert dispatch.jit_cache_sizes()["reid"] <= 4
-        assert len(dispatch._JIT_LRU["reid"]) <= 4
+        reid(D)
+        assert dispatch.jit_cache_sizes()["reid_multi"] <= 4
+        assert len(dispatch._JIT_LRU["reid_multi"]) <= 4
     # A shape inside the live working set does not recompile.
-    size = dispatch.jit_cache_sizes()["reid"]
-    dispatch.reid_match(rng.normal(size=(2, 76)).astype(np.float32),
-                        rng.normal(size=(1, 76)).astype(np.float32))
-    assert dispatch.jit_cache_sizes()["reid"] == size
+    size = dispatch.jit_cache_sizes()["reid_multi"]
+    reid(76)
+    assert dispatch.jit_cache_sizes()["reid_multi"] == size
 
 
 # --------------------------------------------------------------------- #

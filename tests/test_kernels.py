@@ -10,8 +10,8 @@ from repro.kernels.decode_attention.kernel import decode_attention_pallas
 from repro.kernels.decode_attention.ref import decode_attention_ref
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.flash_attention.ref import attention_chunked_ref, attention_ref
-from repro.kernels.reid_match.kernel import reid_match_pallas
-from repro.kernels.reid_match.ref import reid_match_ref
+from repro.kernels import dispatch
+from repro.kernels.reid_ref import reid_match_ref
 from repro.kernels.ssd_scan.kernel import ssd_scan_pallas
 from repro.kernels.ssd_scan.ref import ssd_decode_step_ref, ssd_ref
 
@@ -150,14 +150,18 @@ def test_ssd_decode_step_matches_scan():
 # reid match                                                             #
 # --------------------------------------------------------------------- #
 @pytest.mark.parametrize("N,Q,D,thr", [(100, 3, 64, 0.5), (257, 1, 128, 0.3), (64, 8, 32, 0.9)])
-def test_reid_pallas_matches_ref(N, Q, D, thr):
-    g = jax.random.normal(jax.random.fold_in(KEY, 1), (N, D))
-    q = jax.random.normal(jax.random.fold_in(KEY, 2), (Q, D))
-    s1, b1, m1 = reid_match_ref(g, q, threshold=thr)
-    s2, b2, m2 = reid_match_pallas(g, q, threshold=thr, block_n=64, interpret=True)
-    np.testing.assert_allclose(np.asarray(s1), np.asarray(s2), atol=1e-5)
-    assert np.array_equal(np.asarray(b1), np.asarray(b2))
-    assert np.array_equal(np.asarray(m1), np.asarray(m2))
+def test_reid_matcher_matches_ref(N, Q, D, thr):
+    """The dispatch plane's matcher against the plain reference: the best
+    score per gallery row agrees to 2e-6, and the flags agree wherever the
+    score is not within reach of the threshold."""
+    g = np.asarray(jax.random.normal(jax.random.fold_in(KEY, 1), (N, D)))
+    q = np.asarray(jax.random.normal(jax.random.fold_in(KEY, 2), (Q, D)))
+    s_ref, _, m_ref = [np.asarray(x) for x in reid_match_ref(g, q, threshold=thr)]
+    scores, matched = [np.asarray(x) for x in dispatch.reid_match_multi(g, q, threshold=thr)]
+    assert scores.shape == matched.shape == (N, Q)
+    np.testing.assert_allclose(scores.max(axis=1), s_ref, rtol=0, atol=2e-6)
+    clear = np.abs(s_ref - thr) > 1e-5
+    assert np.array_equal(matched.any(axis=1)[clear], m_ref[clear])
 
 
 def test_reid_match_finds_planted_target():

@@ -228,9 +228,10 @@ def test_reid_match_pipeline():
     tower = init_reid_tower(jax.random.PRNGKey(2), d_in=32, d_embed=16)
     frames = jax.random.normal(jax.random.PRNGKey(3), (20, 32))
     query = embed_frames(tower, frames[5:6])
-    scores, best, is_match = match(tower, frames, query, threshold=0.999)
-    assert bool(is_match[5])
-    assert int(jnp.argmax(scores)) == 5
+    scores, matched = match(tower, frames, query, threshold=0.999)
+    assert scores.shape == matched.shape == (20, 1)
+    assert bool(matched[5, 0])
+    assert int(np.argmax(scores[:, 0])) == 5
 
 
 # --------------------------------------------------------------------- #

@@ -184,20 +184,6 @@ def test_spotlight_ball_dispatch_compiles_for_v5e(one_chip):
     _fits_one_chip(compiled)
 
 
-def test_reid_match_kernel_compiles_for_v5e(one_chip):
-    """The re-ID matcher kernel at the App 4 embedding width (128)."""
-    from repro.kernels.reid_match.kernel import reid_match_pallas
-
-    compiled = jax.jit(
-        lambda g, q: reid_match_pallas(g, q, threshold=0.5, interpret=False)
-    ).lower(
-        _sds(one_chip, (4096, 128), jnp.float32),
-        _sds(one_chip, (8, 128), jnp.float32),
-    ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    _fits_one_chip(compiled)
-
-
 def test_reid_match_multi_compiles_for_v5e(one_chip):
     """The padded query-major matcher a multi-query run's VA batches are
     answered by: the 64-row gallery bucket a flush launches (VA batches hold
